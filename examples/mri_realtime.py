@@ -16,6 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import Environment
+from repro.core.runtime import use_compile_cache
 from repro.nlinv import phantom
 from repro.nlinv.gridding import gridding_recon
 from repro.nlinv.recon import Reconstructor
@@ -44,6 +45,7 @@ def main():
     ap.add_argument("--report", default="",
                     help="write the latency report JSON here")
     args = ap.parse_args()
+    use_compile_cache()
 
     print(f"acquiring {args.frames} frames (n={args.n}, J={args.coils}, "
           f"{args.spokes} spokes, golden-angle)")

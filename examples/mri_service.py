@@ -12,6 +12,7 @@ throughput.
 import argparse
 
 from repro.core import Environment
+from repro.core.runtime import use_compile_cache
 from repro.nlinv import phantom
 from repro.nlinv.recon import Reconstructor
 from repro.serve import NlinvStreamWorkload, ServeConfig, StreamScheduler
@@ -31,6 +32,7 @@ def main():
                     help="per-frame SLO budget (0 = auto: 2x the first "
                          "steady tick)")
     args = ap.parse_args()
+    use_compile_cache()
 
     K = args.clients
     print(f"service: {K} clients, {args.frames} frames each "

@@ -14,7 +14,10 @@ import numpy as np
 
 import jax.numpy as jnp
 from repro.core import Environment, Policy
+from repro.core.runtime import use_compile_cache
 from repro.lib import blas, fft, plan_stats
+
+use_compile_cache()
 
 # -- environment / dev_group (paper §2.1) ----------------------------------
 env = Environment()

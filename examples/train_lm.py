@@ -8,6 +8,7 @@ checkpointing + restart (wraps the production launcher).
 import argparse
 import sys
 
+from repro.core.runtime import use_compile_cache
 from repro.launch.train import main as train_main
 
 if __name__ == "__main__":
@@ -16,6 +17,7 @@ if __name__ == "__main__":
     ap.add_argument("--steps", type=int, default=120)
     ap.add_argument("--ckpt-dir", default="/tmp/repro_train_lm")
     args = ap.parse_args()
+    use_compile_cache()
     train_main(["--arch", args.arch, "--smoke", "--steps", str(args.steps),
                 "--batch", "8", "--seq", "64", "--lr", "1e-2",
                 "--ckpt-dir", args.ckpt_dir, "--ckpt-every", "40"])

@@ -20,6 +20,8 @@ tooling can load without pulling JAX-heavy scenario modules.
 from __future__ import annotations
 
 import dataclasses
+import pathlib
+import pkgutil
 from importlib import import_module
 from typing import Callable, Dict
 
@@ -81,5 +83,10 @@ def scenarios(figures=None) -> Dict[str, Scenario]:
 
 
 def figure_names() -> tuple:
-    """All registered figure names, sorted."""
-    return tuple(sorted({s.figure for s in scenarios().values()}))
+    """All figure names, sorted.  Each ``repro.bench.suites`` module
+    registers exactly the figure it is named after, so the names come
+    from the package listing without importing the suites (and JAX):
+    the sweep parent validates ``--only`` with this and must never
+    initialise a JAX backend."""
+    pkg = pathlib.Path(__file__).with_name("suites")
+    return tuple(sorted(m.name for m in pkgutil.iter_modules([str(pkg)])))

@@ -10,13 +10,15 @@ paper:fig5`` gives the cheap tiny coverage everywhere plus paper-size
 columns for the transfer figures.  When present it replaces
 ``--size``/``--only``.
 
-XLA locks the host device count at first JAX init, so the parent
-process never runs a scenario itself: it spawns one child per requested
-device count with ``--xla_force_host_platform_device_count=N`` (the
-same simulated-device mechanism as ``tests/helpers.py``), collects the
-children's partial results, computes per-scenario speed-ups vs the
+The parent process never initialises a JAX backend (an accelerator
+belongs to one process at a time, and the CPU device count is locked at
+first init): it spawns one child per requested device count, collects
+the children's partial results, computes per-scenario speed-ups vs the
 1-device runs, and writes one schema-versioned artifact
-(``repro.bench.artifact``).  ``--out -`` prints the table only.
+(``repro.bench.artifact``).  ``--out -`` prints the table only.  A
+child asked for N devices sets the CPU backend's device count to N
+(simulated devices, as in ``tests/helpers.py``); on an accelerator host
+with more chips it runs on the first N.
 """
 
 from __future__ import annotations
@@ -25,7 +27,6 @@ import argparse
 import json
 import os
 import pathlib
-import re
 import subprocess
 import sys
 import tempfile
@@ -116,17 +117,21 @@ def _sampling(args):
 def _child_main(args) -> int:
     import jax
 
+    want = int(args.devices)
+    # sizes the CPU backend only; an accelerator's chips are unaffected
+    jax.config.update("jax_num_cpu_devices", want)
+
     from repro.core import Environment
+    from repro.core.runtime import use_compile_cache
 
     from .harness import BenchContext
     from .registry import scenarios
 
-    want = int(args.devices)
+    use_compile_cache()
     got = jax.device_count()
-    if got != want:
+    if got < want:
         print(f"repro.bench: need {want} devices, jax sees {got} "
-              f"(parent sets --xla_force_host_platform_device_count)",
-              file=sys.stderr)
+              f"{jax.devices()[0].platform} device(s)", file=sys.stderr)
         return 2
 
     out_dir = pathlib.Path(args.out_dir)
@@ -154,7 +159,8 @@ def _child_main(args) -> int:
     from .harness import calibrate
     payload = {
         "host": {"platform": jax.devices()[0].platform,
-                 "device_count": got, "jax": jax.__version__,
+                 "device_kind": jax.devices()[0].device_kind,
+                 "device_count": want, "jax": jax.__version__,
                  "python": sys.version.split()[0]},
         "calibration_ms": calibrate(),
         "runs": runs,
@@ -183,10 +189,6 @@ def _spawn(args, ndev: int, size: str, only: str,
     if args.warmup is not None:
         cmd += ["--warmup", str(args.warmup)]
     env = os.environ.copy()
-    flags = re.sub(r"--xla_force_host_platform_device_count=\d+", "",
-                   env.get("XLA_FLAGS", "")).strip()
-    env["XLA_FLAGS"] = (f"{flags} " if flags else "") + \
-        f"--xla_force_host_platform_device_count={ndev}"
     env["PYTHONPATH"] = str(REPO / "src") + os.pathsep + \
         env.get("PYTHONPATH", "")
     r = subprocess.run(cmd, env=env, cwd=str(REPO))

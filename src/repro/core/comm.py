@@ -65,7 +65,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from . import compat
 from .plan import Plan, PlanCache, default_cache, group_token, seg_token
 from .runtime import DeviceGroup, current_group
 from .segmented import (Policy, SegmentedArray, _block_cyclic_perm, _pad_to,
@@ -219,9 +218,9 @@ def plan_broadcast(shape, dtype, group: DeviceGroup,
             parts = [g.reshape(nseg, -1) for g in gathered]
             return jnp.concatenate(parts, axis=1).reshape(-1)
 
-        sm = compat.shard_map(body, mesh=group.mesh,
-                              in_specs=P(_axspec(mesh_axes)), out_specs=P(),
-                              check_vma=False)
+        sm = jax.shard_map(body, mesh=group.mesh,
+                           in_specs=P(_axspec(mesh_axes)), out_specs=P(),
+                           check_vma=False)
 
         def fn(v):
             return sm(v)[:size].reshape(shape)
@@ -299,8 +298,8 @@ def plan_reduce(seg: SegmentedArray, op: str = "sum",
             return pcoll(x, _axis_arg(maxes))
 
         out_spec = P(*[None] * (seg.data.ndim - 1))
-        sm = compat.shard_map(body, mesh=seg.group.mesh, in_specs=seg.pspec,
-                              out_specs=out_spec, check_vma=False)
+        sm = jax.shard_map(body, mesh=seg.group.mesh, in_specs=seg.pspec,
+                           out_specs=out_spec, check_vma=False)
         return jax.jit(sm)
 
     return _plan(key, build, op="reduce", cache=cache,
@@ -394,9 +393,9 @@ def all_reduce_window(x, window=None, *, op: str = "sum",
             out_spec = P(*[None] * (seg.data.ndim - 1))
             # check_vma=False: the windowed scatter-into-zeros defeats
             # JAX's replication inference though the result is replicated.
-            sm = compat.shard_map(body, mesh=seg.group.mesh,
-                                  in_specs=seg.pspec, out_specs=out_spec,
-                                  check_vma=False)
+            sm = jax.shard_map(body, mesh=seg.group.mesh,
+                               in_specs=seg.pspec, out_specs=out_spec,
+                               check_vma=False)
             return jax.jit(sm)
 
         plan = _plan(key, build, op="allreduce",
@@ -541,8 +540,8 @@ def _p2p_eager(seg: SegmentedArray, perm) -> SegmentedArray:
                          f"{seg.nseg}-segment group")
     ax = _axis_arg(seg.mesh_axes)
     body = lambda xl: lax.ppermute(xl, ax, perm)
-    out = compat.shard_map(body, mesh=seg.group.mesh, in_specs=seg.pspec,
-                           out_specs=seg.pspec)(seg.data)
+    out = jax.shard_map(body, mesh=seg.group.mesh, in_specs=seg.pspec,
+                        out_specs=seg.pspec)(seg.data)
     return seg.with_data(out)
 
 
@@ -874,8 +873,8 @@ def _plan_clone_split(src, policy, dim, mesh_axes, block, halo, cache):
 
             spec = [None] * x.ndim
             spec[dim] = _axspec(mesh_axes)
-            sm = compat.shard_map(body, mesh=group.mesh, in_specs=P(),
-                                  out_specs=P(*spec), check_vma=False)
+            sm = jax.shard_map(body, mesh=group.mesh, in_specs=P(),
+                               out_specs=P(*spec), check_vma=False)
             return sm(x)
 
         return jax.jit(fn)
@@ -897,8 +896,8 @@ def _plan_replicate(src, cache):
                 v = lax.all_gather(v, a, axis=sdim, tiled=True)
             return v
 
-        sm = compat.shard_map(body, mesh=src.group.mesh, in_specs=src.pspec,
-                              out_specs=P(), check_vma=False)
+        sm = jax.shard_map(body, mesh=src.group.mesh, in_specs=src.pspec,
+                           out_specs=P(), check_vma=False)
         return jax.jit(sm)
 
     return _plan(key, build, op="copy", cache=cache,
@@ -941,8 +940,8 @@ def _plan_block_exchange(src, block: int, pack: bool, cache):
                 r = jnp.moveaxis(r, 0, 1).reshape(m * block, *rest)
             return jnp.moveaxis(r, 0, dim)
 
-        sm = compat.shard_map(body, mesh=src.group.mesh, in_specs=src.pspec,
-                              out_specs=src.pspec, check_vma=False)
+        sm = jax.shard_map(body, mesh=src.group.mesh, in_specs=src.pspec,
+                           out_specs=src.pspec, check_vma=False)
         return jax.jit(sm)
 
     return _plan(key, build, op="copy", cache=cache,
@@ -1033,9 +1032,9 @@ def plan_all_to_all(seg: SegmentedArray, new_dim: int,
             x, _ = _pad_to(x, new_dim, nseg)
             out = [None] * x.ndim
             out[new_dim] = _axspec(mesh_axes)
-            sm = compat.shard_map(body, mesh=seg.group.mesh,
-                                  in_specs=seg.pspec, out_specs=P(*out),
-                                  check_vma=False)
+            sm = jax.shard_map(body, mesh=seg.group.mesh,
+                               in_specs=seg.pspec, out_specs=P(*out),
+                               check_vma=False)
             y = sm(x)
             if sorig is not None and sorig != shape[sdim]:
                 # old-dim padding sits at the global tail; it is local to
@@ -1111,8 +1110,8 @@ def plan_reduce_scatter(seg: SegmentedArray, op: str = "sum",
         merged_ndim = seg.data.ndim - 1
         out = [None] * merged_ndim
         out[0] = _axspec(mesh_axes)
-        sm = compat.shard_map(body, mesh=seg.group.mesh, in_specs=seg.pspec,
-                              out_specs=P(*out), check_vma=False)
+        sm = jax.shard_map(body, mesh=seg.group.mesh, in_specs=seg.pspec,
+                           out_specs=P(*out), check_vma=False)
         return jax.jit(sm)
 
     return _plan(key, build, op="reduce_scatter", cache=cache,

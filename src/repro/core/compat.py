@@ -1,11 +1,5 @@
-"""JAX version binding — the single place repro.core touches the host
-JAX API surface that moved between releases.
-
-The library targets everything from jax 0.4.3x (``shard_map`` still in
-``jax.experimental``, no ``jax.sharding.AxisType``, ambient mesh only
-via thread resources) through 0.6+ (``jax.shard_map`` with ``check_vma``,
-``get_abstract_mesh``).  Everything else in repro.core is written against
-the thin functions here, so a JAX upgrade is a one-file change.
+"""Mesh helpers over the installed JAX (0.9): mesh construction from a
+device list, and the ambient mesh context.
 """
 
 from __future__ import annotations
@@ -15,80 +9,49 @@ from typing import Sequence
 
 import jax
 import numpy as np
+from jax.experimental import mesh_utils
 from jax.sharding import Mesh
-
-_HAS_TOPLEVEL_SHARD_MAP = hasattr(jax, "shard_map")
-_HAS_ABSTRACT_MESH = hasattr(jax.sharding, "get_abstract_mesh")
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` with the ``check_vma``/``check_rep`` rename
-    papered over."""
-    if _HAS_TOPLEVEL_SHARD_MAP:
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str],
               devices=None) -> Mesh:
-    """Build a Mesh over the first ``prod(shape)`` devices.
+    """Build a Mesh over ``devices`` (default: the first ``prod(shape)``
+    devices).
 
-    Unlike ``jax.make_mesh`` this tolerates a mesh smaller than the host
-    device count, so the same test/benchmark code runs under any
-    ``--xla_force_host_platform_device_count``.  Axis types default to
-    Auto on every supported JAX.
+    Unlike ``jax.make_mesh`` this accepts a mesh smaller than the host
+    device count, so the same code runs under any
+    ``--xla_force_host_platform_device_count``.  When the devices are
+    all of the host's TPU chips, their order is topology-aware
+    (``create_device_mesh``: ICI nearest-neighbour rings); a CPU mesh or
+    a subset of the chips keeps the given order.
     """
     shape = tuple(shape)
     if devices is None:
         devices = jax.devices()[: math.prod(shape)]
+    devices = list(devices)
     if len(devices) != math.prod(shape):
         raise ValueError(f"mesh shape {shape} needs {math.prod(shape)} "
                          f"devices, got {len(devices)}")
-    try:
-        # topology-aware ordering (ICI nearest-neighbour rings on TPU)
-        from jax.experimental import mesh_utils
-        arr = mesh_utils.create_device_mesh(shape, devices=list(devices))
-    except Exception:
+    whole_tpu_host = (devices[0].platform == "tpu"
+                      and set(devices) == set(jax.local_devices()))
+    if whole_tpu_host:
+        arr = mesh_utils.create_device_mesh(shape, devices=devices)
+    else:
         arr = np.asarray(devices).reshape(shape)
     return Mesh(arr, tuple(axes))
 
 
-def pallas_tpu_compiler_params(**kwargs):
-    """``pltpu.CompilerParams`` across its ``TPUCompilerParams`` rename."""
-    from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
-    return cls(**kwargs)
-
-
 def ambient_mesh() -> Mesh | None:
-    """The innermost ``with mesh:`` context as a concrete Mesh, or None."""
-    if _HAS_ABSTRACT_MESH:
-        env = jax.sharding.get_abstract_mesh()
-        if env is None or env.empty:
-            return None
-        try:
-            return jax.sharding.get_concrete_mesh()
-        except Exception:
-            return None
-    try:
-        from jax._src.mesh import thread_resources
-        m = thread_resources.env.physical_mesh
-        return None if m.empty else m
-    except Exception:
-        return None
+    """The mesh set by ``jax.set_mesh`` as a concrete Mesh, or None."""
+    mesh = jax.sharding.get_mesh()
+    return None if mesh.empty else mesh
 
 
 def ambient_axis_names() -> tuple[str, ...]:
-    """Axis names of the ambient mesh context (abstract or concrete);
-    empty outside any mesh scope.  Safe to call while tracing."""
-    if _HAS_ABSTRACT_MESH:
-        env = jax.sharding.get_abstract_mesh()
-        if env is None or env.empty:
-            return ()
-        return tuple(env.shape.keys())
-    m = ambient_mesh()
-    return () if m is None else tuple(m.axis_names)
+    """Axis names of the ambient abstract mesh (``jax.set_mesh`` /
+    ``use_abstract_mesh``); empty outside any mesh scope.  Safe to call
+    while tracing."""
+    env = jax.sharding.get_abstract_mesh()
+    if env is None or env.empty:
+        return ()
+    return tuple(env.shape.keys())

@@ -23,7 +23,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from . import compat
 from .runtime import DeviceGroup, current_group
 from .segmented import Policy, SegmentedArray
 
@@ -78,8 +77,8 @@ def invoke_kernel_all(fn: Callable, *args,
         out = [None] * _out_ndim_probe(probe_fn or fn, vals, in_specs, group)
         out[out_dim] = mesh_axes if len(mesh_axes) > 1 else mesh_axes[0]
         out_specs = P(*out)
-    res = compat.shard_map(fn, mesh=group.mesh, in_specs=in_specs,
-                           out_specs=out_specs)(*vals)
+    res = jax.shard_map(fn, mesh=group.mesh, in_specs=in_specs,
+                        out_specs=out_specs)(*vals)
     if out_specs == P() or all(s is None for s in out_specs):
         return res
     return SegmentedArray(res, group, out_policy, out_dim, tuple(mesh_axes))
@@ -143,10 +142,10 @@ def make_spmd(fn: Callable, group: DeviceGroup | None = None, *,
     axis = mesh_axes if len(mesh_axes) > 1 else mesh_axes[0]
     to_specs = lambda pol: jax.tree.map(lambda p: policy_pspec(p, axis),
                                         pol, is_leaf=_is_policy_leaf)
-    sm = compat.shard_map(fn, mesh=group.mesh,
-                          in_specs=tuple(to_specs(p) for p in in_policies),
-                          out_specs=to_specs(out_policies),
-                          check_vma=check_vma)
+    sm = jax.shard_map(fn, mesh=group.mesh,
+                       in_specs=tuple(to_specs(p) for p in in_policies),
+                       out_specs=to_specs(out_policies),
+                       check_vma=check_vma)
     if not jit:
         if donate_argnums:
             raise ValueError("donate_argnums requires jit=True")

@@ -13,6 +13,8 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import os
+import pathlib
 from typing import Mapping, Sequence
 
 import jax
@@ -120,6 +122,24 @@ class DeviceGroup:
 
     def __exit__(self, *exc):
         return self._ctx.__exit__(*exc)
+
+
+REPO_ROOT = pathlib.Path(__file__).resolve().parents[3]
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point
+    (scripts and examples call this first; library imports never do, so
+    tests stay cache-free).  ``JAX_COMPILATION_CACHE_DIR``, when set,
+    already places the cache and is left alone; otherwise the cache is
+    the fixed ``.jax_cache/`` at the repository root — a fixed path,
+    because the directory is part of what a later run must find again.
+    Returns the directory in use."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(REPO_ROOT / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def current_group(group=None) -> DeviceGroup:

@@ -29,7 +29,6 @@ import numpy as np
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from . import compat
 from .runtime import DeviceGroup, current_group
 
 
@@ -462,8 +461,8 @@ def overlap2d_map(seg: SegmentedArray,
         return ext if fn is None else fn(ext)
 
     spec = seg.pspec
-    out = compat.shard_map(body, mesh=mesh, in_specs=spec,
-                           out_specs=spec)(seg.data)
+    out = jax.shard_map(body, mesh=mesh, in_specs=spec,
+                        out_specs=spec)(seg.data)
     if fn is None:
         return SegmentedArray(out, seg.group, Policy.NATURAL, seg.dim,
                               seg.mesh_axes, orig_len=out.shape[seg.dim])

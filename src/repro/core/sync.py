@@ -24,7 +24,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from . import compat
 from .runtime import DeviceGroup, current_group
 
 
@@ -38,9 +37,9 @@ def barrier(group: DeviceGroup | None = None) -> None:
     """All devices of the group reach this point (tiny psum round-trip)."""
     group = current_group(group)
     token = jnp.zeros((), jnp.int32)
-    out = compat.shard_map(
-        lambda t: lax.psum(t, group.axis_names
-                           if len(group.axis_names) > 1 else group.axis_names[0]),
+    out = jax.shard_map(
+        lambda t: lax.psum(t, group.axis_names if len(group.axis_names) > 1
+                           else group.axis_names[0]),
         mesh=group.mesh, in_specs=P(), out_specs=P())(token)
     jax.block_until_ready(out)
 

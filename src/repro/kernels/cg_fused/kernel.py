@@ -13,6 +13,12 @@ the (8,128) VREG lanes natively (same convention as ``coil_mult`` /
 ``ops.py``; the grid walks row blocks sequentially (``arbitrary``) so
 the scalar epilogue accumulates across blocks in SMEM scratch.
 
+Scalars (``alpha``/``beta`` in, ``rs``/``d`` out) are (1, 1) SMEM
+arrays: under ``jax.vmap`` the batch becomes a leading grid dim and the
+block keeps the array's last two dims, the form Mosaic's tiling rule
+accepts (a (1,) scalar would become a (B, 1) array with an untileable
+(1, 1) block of it).
+
   cg_update: x' = x + a*p, r' = r - a*Ap, rs = sum |r'|^2
   xpby_dot:  w  = x + b*y,                d  = sum |w|^2
 """
@@ -26,11 +32,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...core.compat import pallas_tpu_compiler_params
-
 
 def _scalar_spec():
     return pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
+_SCALAR = jax.ShapeDtypeStruct((1, 1), jnp.float32)
 
 
 def _cg_update_kernel(alpha, pr, pi, apr, api, xr, xi, rr, ri,
@@ -41,7 +48,7 @@ def _cg_update_kernel(alpha, pr, pi, apr, api, xr, xi, rr, ri,
     def _init():
         acc[0, 0] = 0.0
 
-    a = alpha[0]
+    a = alpha[0, 0]
     xro[...] = xr[...] + a * pr[...]
     xio[...] = xi[...] + a * pi[...]
     r2r = rr[...] - a * apr[...]
@@ -52,14 +59,14 @@ def _cg_update_kernel(alpha, pr, pi, apr, api, xr, xi, rr, ri,
 
     @pl.when(i == nblk - 1)
     def _final():
-        rso[0] = acc[0, 0]
+        rso[0, 0] = acc[0, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "interpret"))
 def cg_update_pallas(alpha, pr, pi, apr, api, xr, xi, rr, ri, *,
                      bm=32, interpret=True):
-    """Planes are (M, Y) f32; ``alpha`` is a (1,) f32 array (SMEM).
-    Returns (xr', xi', rr', ri', rs) with ``rs`` a (1,) f32."""
+    """Planes are (M, Y) f32; ``alpha`` is a (1, 1) f32 array (SMEM).
+    Returns (xr', xi', rr', ri', rs) with ``rs`` a (1, 1) f32."""
     M, Y = pr.shape
     bm = min(bm, M)
     assert M % bm == 0
@@ -72,16 +79,16 @@ def cg_update_pallas(alpha, pr, pi, apr, api, xr, xi, rr, ri, *,
         in_specs=[_scalar_spec()] + [row] * 8,
         out_specs=[row] * 4 + [_scalar_spec()],
         out_shape=[jax.ShapeDtypeStruct((M, Y), pr.dtype)] * 4 +
-                  [jax.ShapeDtypeStruct((1,), jnp.float32)],
+                  [_SCALAR],
         scratch_shapes=[pltpu.SMEM((1, 1), jnp.float32)],
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(alpha, pr, pi, apr, api, xr, xi, rr, ri)
 
 
 def _xpby_kernel(beta, xr, xi, yr, yi, wro, wio):
-    b = beta[0]
+    b = beta[0, 0]
     wro[...] = xr[...] + b * yr[...]
     wio[...] = xi[...] + b * yi[...]
 
@@ -101,7 +108,7 @@ def xpby_pallas(beta, xr, xi, yr, yi, *, bm=32, interpret=True):
         in_specs=[_scalar_spec()] + [row] * 4,
         out_specs=[row] * 2,
         out_shape=[jax.ShapeDtypeStruct((M, Y), xr.dtype)] * 2,
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(beta, xr, xi, yr, yi)
@@ -114,7 +121,7 @@ def _xpby_dot_kernel(beta, xr, xi, yr, yi, wro, wio, do, acc, *, nblk):
     def _init():
         acc[0, 0] = 0.0
 
-    b = beta[0]
+    b = beta[0, 0]
     wr = xr[...] + b * yr[...]
     wi = xi[...] + b * yi[...]
     wro[...] = wr
@@ -123,13 +130,13 @@ def _xpby_dot_kernel(beta, xr, xi, yr, yi, wro, wio, do, acc, *, nblk):
 
     @pl.when(i == nblk - 1)
     def _final():
-        do[0] = acc[0, 0]
+        do[0, 0] = acc[0, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "interpret"))
 def xpby_dot_pallas(beta, xr, xi, yr, yi, *, bm=32, interpret=True):
-    """Planes are (M, Y) f32; ``beta`` is a (1,) f32 array (SMEM).
-    Returns (wr, wi, d) with ``d`` a (1,) f32."""
+    """Planes are (M, Y) f32; ``beta`` is a (1, 1) f32 array (SMEM).
+    Returns (wr, wi, d) with ``d`` a (1, 1) f32."""
     M, Y = xr.shape
     bm = min(bm, M)
     assert M % bm == 0
@@ -142,9 +149,9 @@ def xpby_dot_pallas(beta, xr, xi, yr, yi, *, bm=32, interpret=True):
         in_specs=[_scalar_spec()] + [row] * 4,
         out_specs=[row] * 2 + [_scalar_spec()],
         out_shape=[jax.ShapeDtypeStruct((M, Y), xr.dtype)] * 2 +
-                  [jax.ShapeDtypeStruct((1,), jnp.float32)],
+                  [_SCALAR],
         scratch_shapes=[pltpu.SMEM((1, 1), jnp.float32)],
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(beta, xr, xi, yr, yi)

@@ -108,7 +108,7 @@ def cg_update(alpha, p, ap, x, r, impl="auto", block=None):
     impl, block = CG_UPDATE.resolve(impl, block, alpha, p, ap, x, r)
     if impl != "pallas":
         return cg_update_ref(alpha, p, ap, x, r)
-    a = jnp.reshape(jnp.real(alpha).astype(jnp.float32), (1,))
+    a = jnp.reshape(jnp.real(alpha).astype(jnp.float32), (1, 1))
     pr, pi, apr, api, xr, xi, rr, ri = [
         *planes(p), *planes(ap), *planes(x), *planes(r)]
     xr2, xi2, rr2, ri2, rs = cg_update_pallas(
@@ -116,7 +116,7 @@ def cg_update(alpha, p, ap, x, r, impl="auto", block=None):
         bm=block[0], interpret=not on_tpu())
     x2 = (xr2 + 1j * xi2).reshape(x.shape).astype(x.dtype)
     r2 = (rr2 + 1j * ri2).reshape(r.shape).astype(r.dtype)
-    return x2, r2, rs[0]
+    return x2, r2, rs[0, 0]
 
 
 CG_UPDATE.dispatch = cg_update
@@ -132,7 +132,7 @@ def xpby_dot(x, y, beta, impl="auto", with_dot=True, block=None):
         if not with_dot:
             return x + beta * y, None
         return xpby_dot_ref(x, y, beta)
-    b = jnp.reshape(jnp.real(beta).astype(jnp.float32), (1,))
+    b = jnp.reshape(jnp.real(beta).astype(jnp.float32), (1, 1))
     xr, xi = planes(x)
     yr, yi = planes(y)
     if not with_dot:
@@ -142,7 +142,7 @@ def xpby_dot(x, y, beta, impl="auto", with_dot=True, block=None):
     wr, wi, d = xpby_dot_pallas(b, xr, xi, yr, yi,
                                 bm=block[0], interpret=not on_tpu())
     w = (wr + 1j * wi).reshape(x.shape).astype(x.dtype)
-    return w, d[0]
+    return w, d[0, 0]
 
 
 XPBY_DOT.dispatch = xpby_dot

@@ -23,8 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...core.compat import pallas_tpu_compiler_params
-
 
 def _fwd_kernel(cr, ci, xr, xi, zr, zi):
     a, b = cr[0], ci[0]
@@ -53,7 +51,7 @@ def coil_forward_pallas(cr, ci, xr, xi, *, bx=32, interpret=True):
             pl.BlockSpec((1, bx, Y), lambda j, i: (j, i, 0)),
         ],
         out_shape=[jax.ShapeDtypeStruct((J, X, Y), cr.dtype)] * 2,
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(cr, ci, xr, xi)
@@ -88,7 +86,7 @@ def coil_lincomb_pallas(ar, ai, xr, xi, br, bi, yr, yi, s, *,
                   plane, plane, stack, stack, plane],
         out_specs=[stack, stack],
         out_shape=[jax.ShapeDtypeStruct((J, X, Y), xr.dtype)] * 2,
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(ar, ai, xr, xi, br, bi, yr, yi, s)
@@ -118,7 +116,7 @@ def coil_scale_mult_pallas(ar, ai, xr, xi, s, *, bx=32, interpret=True):
         in_specs=[plane, plane, stack, stack, plane],
         out_specs=[stack, stack],
         out_shape=[jax.ShapeDtypeStruct((J, X, Y), xr.dtype)] * 2,
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(ar, ai, xr, xi, s)
@@ -144,7 +142,7 @@ def plane_mult_pallas(zr, zi, m, *, bx=32, interpret=True):
         in_specs=[stack, stack, plane],
         out_specs=[stack, stack],
         out_shape=[jax.ShapeDtypeStruct((J, X, Y), zr.dtype)] * 2,
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(zr, zi, m)
@@ -192,7 +190,7 @@ def coil_adjoint_pallas(cr, ci, zr, zi, mask, *, bx=32, interpret=True):
         ],
         out_shape=[jax.ShapeDtypeStruct((X, Y), cr.dtype)] * 2,
         scratch_shapes=[pltpu.VMEM((bx, Y), jnp.float32)] * 2,
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(cr, ci, zr, zi, mask)

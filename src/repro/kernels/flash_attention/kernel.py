@@ -27,7 +27,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...core.compat import pallas_tpu_compiler_params
 
 NEG_INF = -1e30
 
@@ -140,7 +139,7 @@ def flash_attention_pallas(q, k, v, kv_len=None, *, causal=True, window=None,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, Hq, S, Dv), q.dtype),
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

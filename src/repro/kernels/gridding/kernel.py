@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...core.compat import pallas_tpu_compiler_params
-
 
 def _degrid_kernel(ax, ay, gr, gi, outr, outi):
     a = ax[...]                              # (bs, X)
@@ -59,7 +57,7 @@ def degrid_pallas(ax, ay, gr, gi, *, bs=128, interpret=True):
             pl.BlockSpec((1, bs), lambda j, s: (j, s)),
         ],
         out_shape=[jax.ShapeDtypeStruct((J, S), jnp.float32)] * 2,
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(ax, ay, gr, gi)
@@ -111,7 +109,7 @@ def grid_pallas(ax, ay, yr, yi, *, bs=128, interpret=True):
         ],
         out_shape=[jax.ShapeDtypeStruct((J, X, Y), jnp.float32)] * 2,
         scratch_shapes=[pltpu.VMEM((X, Y), jnp.float32)] * 2,
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(ax, ay, yr, yi)

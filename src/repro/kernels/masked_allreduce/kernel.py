@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...core.compat import pallas_tpu_compiler_params
-
 
 def _kernel(pr, pi, m, outr, outi):
     outr[...] = jnp.sum(pr[...], axis=0) * m[...]
@@ -46,7 +44,7 @@ def masked_sum_pallas(pr, pi, mask, *, bx=32, interpret=True):
             pl.BlockSpec((bx, Y), lambda i: (i, 0)),
         ],
         out_shape=[jax.ShapeDtypeStruct((X, Y), pr.dtype)] * 2,
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(pr, pi, mask)

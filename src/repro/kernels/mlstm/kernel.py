@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...core.compat import pallas_tpu_compiler_params
 
 NEG = -1e30
 
@@ -124,7 +123,7 @@ def mlstm_pallas(q, k, v, log_i, log_f, state=None, *, chunk=128,
             pltpu.VMEM((1, dk), jnp.float32),
             pltpu.VMEM((1, 1), jnp.float32),
         ],
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, log_i, log_f)

@@ -34,6 +34,7 @@ even off-TPU (interpret mode — test/diagnostic use only).
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import importlib
 import math
@@ -156,6 +157,10 @@ class KernelSpec:
                     else self.fallback)
         elif impl == "pallas" and not self.supports(block, *args, **kw):
             impl = self.fallback
+        # the dispatch entries run Pallas with ``interpret=not on_tpu()``
+        label = impl if impl != "pallas" or on_tpu() else "pallas-interpret"
+        with _LOCK:
+            _TALLY[(self.id, label)] += 1
         return impl, block
 
     def block_kw(self, block) -> dict:
@@ -165,6 +170,9 @@ class KernelSpec:
 
 _REGISTRY: dict[str, KernelSpec] = {}
 _CHOICES: dict[str, dict] = {}
+# (spec id, impl label) -> resolutions; labels: "pallas" (compiled
+# Mosaic), "pallas-interpret", or the spec's fallback name
+_TALLY: collections.Counter = collections.Counter()
 _LOCK = threading.Lock()
 _TUNE_CACHE = PlanCache(maxsize=512)
 _ensured = False
@@ -290,6 +298,24 @@ def reset_choices() -> None:
     """Drop recorded choices (tests); pins and the tune cache remain."""
     with _LOCK:
         _CHOICES.clear()
+
+
+def tally() -> dict:
+    """Which impl every dispatch resolved to since :func:`reset_tally`:
+    ``{spec id: {label: count}}``.  Resolution happens at trace time, so
+    a count is one traced call site, not one execution — what it proves
+    is which code a compiled program contains (Mosaic kernel, interpret
+    mode, or the jnp fallback)."""
+    out: dict[str, dict[str, int]] = {}
+    with _LOCK:
+        for (sid, label), n in sorted(_TALLY.items()):
+            out.setdefault(sid, {})[label] = n
+    return out
+
+
+def reset_tally() -> None:
+    with _LOCK:
+        _TALLY.clear()
 
 
 def tune_cache() -> PlanCache:

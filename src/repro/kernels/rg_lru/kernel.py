@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...core.compat import pallas_tpu_compiler_params
-
 
 def _kernel(log_a_ref, b_ref, h0_ref, h_ref, hlast_ref, hs_ref, *,
             bs: int, ns: int):
@@ -71,7 +69,7 @@ def rg_lru_pallas(log_a, b, h0, *, bb=8, bw=128, bs=256, interpret=True):
             jax.ShapeDtypeStruct((B, W), b.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((bb, bw), jnp.float32)],
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(log_a, b, h0)

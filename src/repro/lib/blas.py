@@ -28,7 +28,6 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from ..core import comm as _comm
-from ..core import compat
 from ..core.comm import _axis_arg
 from ..core.segmented import Policy, SegmentedArray
 from ..kernels import registry as _kreg
@@ -254,8 +253,8 @@ def dot_allreduce(x: SegmentedArray, y: SegmentedArray,
             part = jnp.vdot(xl, yl)
             return part if is_clone else lax.psum(part, ax)
 
-        sm = compat.shard_map(body, mesh=x.group.mesh,
-                              in_specs=(x.pspec, y.pspec), out_specs=P())
+        sm = jax.shard_map(body, mesh=x.group.mesh,
+                           in_specs=(x.pspec, y.pspec), out_specs=P())
         return jax.jit(sm)
 
     plan = _binary_plan("dot_allreduce", x, y, build, cache)
@@ -314,9 +313,9 @@ def gemm_ksplit(a: SegmentedArray, b: SegmentedArray,
                 return _comm._psum_rs_ag(part, tuple(a.mesh_axes))
             return lax.psum(part, ax)
 
-        sm = compat.shard_map(body, mesh=a.group.mesh,
-                              in_specs=(P(None, ax), P(ax, None)),
-                              out_specs=P(), check_vma=False)
+        sm = jax.shard_map(body, mesh=a.group.mesh,
+                           in_specs=(P(None, ax), P(ax, None)),
+                           out_specs=P(), check_vma=False)
         return jax.jit(sm)
 
     plan = _binary_plan("gemm_ksplit", a, b, build, cache,

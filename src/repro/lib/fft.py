@@ -31,7 +31,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from ..core import compat
 from ..core.segmented import Policy, SegmentedArray
 from .plan import Plan, PlanCache, default_cache, seg_token
 
@@ -164,8 +163,8 @@ def _build_fft2_fused(seg: SegmentedArray, inverse: bool, centered: bool,
 
     spec = [None] * nd
     spec[seg_ax] = ax
-    sm = compat.shard_map(body, mesh=seg.group.mesh, in_specs=P(*spec),
-                          out_specs=P(*spec), check_vma=False)
+    sm = jax.shard_map(body, mesh=seg.group.mesh, in_specs=P(*spec),
+                       out_specs=P(*spec), check_vma=False)
     arr_fn = jax.jit(sm)
     return (lambda s: s.with_data(arr_fn(s.data))), chunks
 

@@ -10,6 +10,9 @@ signatures only.
 
 import inspect
 
+import jax
+import pytest
+
 import repro.core as core
 from repro.core import Communicator, Environment
 
@@ -384,3 +387,27 @@ def test_serve_unified_scheduler():
     # the old bespoke driver internals are gone from the front door
     assert not hasattr(Engine, "_admit")
     assert "def _admit" not in src and "self.active" not in src
+
+
+@pytest.mark.parametrize("env_dir", [None, "/var/cache/jax-shared"],
+                         ids=["default", "from-env"])
+def test_use_compile_cache_placement(monkeypatch, env_dir):
+    """Entry points place JAX's persistent compile cache: where
+    ``JAX_COMPILATION_CACHE_DIR`` is set it is left there (nothing is
+    set in code), otherwise at the fixed ``.jax_cache/`` of the repo."""
+    from repro.core.runtime import REPO_ROOT, use_compile_cache
+    prev = jax.config.jax_compilation_cache_dir
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    try:
+        got = use_compile_cache()
+        if env_dir is None:
+            assert got == str(REPO_ROOT / ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+        else:
+            assert got == env_dir
+            assert jax.config.jax_compilation_cache_dir == prev
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)

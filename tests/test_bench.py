@@ -369,6 +369,33 @@ def test_compare_tooling_is_jax_free():
     assert r.returncode == 0 and "jax-free OK" in r.stdout, r.stderr
 
 
+def test_figure_names_match_registered_figures():
+    """The sweep parent lists figures from the suite module names (no
+    JAX import); that list must be exactly what the suites register."""
+    from repro.bench.registry import figure_names
+    assert figure_names() == tuple(sorted(
+        {s.figure for s in scenarios().values()}))
+
+
+def test_sweep_parent_is_jax_free():
+    """The sweep parent validates ``--only`` and spawns children
+    without importing JAX: an accelerator belongs to one process at a
+    time, so a parent holding it would starve every child."""
+    code = (
+        "import sys\n"
+        "sys.modules['jax'] = None   # poison: any 'import jax' raises\n"
+        "from repro.bench import run\n"
+        "try:\n"
+        "    run.main(['--only', 'fig99', '--devices', '1'])\n"
+        "except SystemExit as e:\n"
+        "    print('exit:', e)\n")
+    r = subprocess.run([sys.executable, "-c", code],
+                       capture_output=True, text=True, timeout=60,
+                       env={**os.environ, "PYTHONPATH": str(REPO / "src")})
+    assert r.returncode == 0, r.stderr
+    assert "unknown figure" in r.stdout
+
+
 def test_run_cli_rejects_unknown_figure(tmp_path):
     out = tmp_path / "bench.json"
     r = subprocess.run(

@@ -165,7 +165,7 @@ def test_fused_cg_matches_unfused_4dev():
 
 OVERLAP_PARITY = """
 from functools import partial
-from repro.core import Environment, compat
+from repro.core import Environment
 from repro.core.comm import ring_allreduce, all_reduce_overlap
 from jax.sharding import PartitionSpec as P
 
@@ -175,8 +175,8 @@ x = (np.random.randn(4, 8, 16) + 1j * np.random.randn(4, 8, 16)
      ).astype(np.complex64)
 
 def run(body):
-    sm = compat.shard_map(body, mesh=mesh, in_specs=P("data"),
-                          out_specs=P(), check_vma=False)
+    sm = jax.shard_map(body, mesh=mesh, in_specs=P("data"),
+                       out_specs=P(), check_vma=False)
     return np.asarray(jax.jit(sm)(x))
 
 plain = run(lambda xl: ring_allreduce(xl[0], "data", 4))

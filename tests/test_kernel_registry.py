@@ -107,6 +107,20 @@ def test_fallback_equivalence(spec, i):
     _assert_close(got, want, tol, f"{spec.id} sample {i} ({impl})")
 
 
+@pytest.mark.parametrize("spec", SPECS, ids=IDS)
+def test_tally_records_resolved_impl(spec):
+    """The trace-time tally names what each dispatch resolved to:
+    off-TPU, ``auto`` takes the fallback and an explicit ``pallas`` runs
+    in interpret mode; on a TPU both are the compiled kernel."""
+    args, kw, _, _ = _case(spec, 0)
+    registry.reset_tally()
+    spec.resolve("auto", None, *args, **kw)
+    spec.resolve("pallas", None, *args, **kw)
+    want = ({"pallas": 2} if registry.on_tpu()
+            else {spec.fallback: 1, "pallas-interpret": 1})
+    assert registry.tally() == {spec.id: want}
+
+
 @pytest.mark.parametrize("spec,i", CASES, ids=CASE_IDS)
 def test_output_dtypes_match_oracle(spec, i):
     args, kw, want, _ = _case(spec, i)

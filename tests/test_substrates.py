@@ -192,9 +192,8 @@ def test_compressed_psum_single_device_accuracy():
     def run(gg, err):
         return compressed_psum(gg, "d", err)
 
-    from repro.core.compat import shard_map
-    f = shard_map(run, mesh=mesh, in_specs=(P(), P()),
-                  out_specs=(P(), P()))
+    f = jax.shard_map(run, mesh=mesh, in_specs=(P(), P()),
+                      out_specs=(P(), P()))
     out, err = f(g, jnp.zeros_like(g))
     q_err = float(jnp.abs(out - g).max())
     assert q_err < 0.01 * 2 / 127 + 1e-6        # block absmax / 127
