@@ -11,7 +11,8 @@ and each metric is read by ``chipbench/metrics/<metric>.py``.  A run:
      program the window runs, and is set-up;
   3. measures for ``seconds``: rounds of one frame per scanner, closed
      loop, until the time is up (the last round runs to its end, and the
-     window ends with it);
+     window ends with it); a traced run profiles the mix's
+     ``trace_rounds`` rounds that follow the window's first round;
   4. reads the peak device memory, frees the program, and compares the
      served images of the first rounds with the plain reference;
   5. reads the metrics of the cell (end-to-end ones, or with ``trace``
@@ -36,12 +37,13 @@ import time
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 
 
-def use_checkout_cache(root: pathlib.Path) -> None:
-    """Keep JAX's persistent compilation cache at a fixed path inside the
-    checkout, with no size cap (one frame program's entry is over
-    100 MiB) and every program in it, so that only the first run of a
-    cell compiles.  Call before JAX is imported."""
-    os.environ["JAX_COMPILATION_CACHE_DIR"] = str(root / ".jax_cache")
+def keep_every_program() -> None:
+    """Lift the persistent compilation cache's size cap (one frame
+    program's entry is over 100 MiB, and a cap of 192 MiB evicts one
+    program while writing the next) and cache programs however fast they
+    compiled, so that only the first run of a cell compiles.  Where the
+    cache lives is left to ``use_compile_cache()``.  Call before JAX is
+    imported."""
     os.environ["JAX_COMPILATION_CACHE_MAX_SIZE"] = "-1"
     os.environ["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = "0"
 
@@ -173,43 +175,36 @@ def _cache_entries(path) -> list:
                    for f in os.listdir(path)), reverse=True)
 
 
-# A traced run profiles only the window's first rounds, this long: a
-# trace of four chips holds about a million operations a second.
-TRACE_S = 3.0
-
-
-def _rounds(entry, f: int, until: float, t0: float, served: list) -> int:
-    while True:
-        served.extend(entry.round(f))
-        f += 1
-        if time.perf_counter() - t0 >= until:
-            return f
-
-
-def serve_window(entry, seconds: float, first_round: int, trace_dir=None):
+def serve_window(entry, seconds: float, first_round: int, trace_dir=None,
+                 trace_rounds: int = 0):
     """Rounds until ``seconds`` have passed.  With ``trace_dir``, the
-    rounds of the first ``TRACE_S`` seconds run under the profiler.
-    Returns (served, window_s, rounds, traced), ``traced`` being the
-    (outcomes, rounds, seconds) of the profiled start, or None."""
+    ``trace_rounds`` rounds after the window's first round run under the
+    profiler (a fixed count, so that every traced run reads the same
+    work; a trace of four chips holds about a million operations a
+    second).  Returns (served, window_s, rounds, traced), ``traced`` being
+    the (first round, rounds, seconds) of the profiled part, or None."""
     import jax
     served, f, traced = [], first_round, None
+    t0 = time.perf_counter()
     if trace_dir is not None:
+        served.extend(entry.round(f))
+        f += 1
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0
         jax.profiler.start_trace(trace_dir, profiler_options=opts)
-    t0 = time.perf_counter()
-    try:
-        with jax.profiler.TraceAnnotation("chipbench.window"):
-            f = _rounds(entry, f, seconds if trace_dir is None
-                        else min(TRACE_S, seconds), t0, served)
-        traced = (len(served), f - first_round, time.perf_counter() - t0)
-    finally:
-        if trace_dir is not None:
+        try:
+            t1 = time.perf_counter()
+            with jax.profiler.TraceAnnotation("chipbench.window"):
+                for _ in range(trace_rounds):
+                    served.extend(entry.round(f))
+                    f += 1
+            traced = (f - trace_rounds, trace_rounds, time.perf_counter() - t1)
+        finally:
             jax.profiler.stop_trace()
-    if time.perf_counter() - t0 < seconds:
-        f = _rounds(entry, f, seconds, t0, served)
-    return (served, time.perf_counter() - t0, f - first_round,
-            traced if trace_dir is not None else None)
+    while f == first_round or time.perf_counter() - t0 < seconds:
+        served.extend(entry.round(f))
+        f += 1
+    return served, time.perf_counter() - t0, f - first_round, traced
 
 
 def compare(cell: Cell, traffic: dict, kept: dict, device):
@@ -239,8 +234,7 @@ def compare(cell: Cell, traffic: dict, kept: dict, device):
 
 
 def run(root: pathlib.Path, workload: str, seed: int, seconds: float,
-        traced: bool, *, t_start: float, check_device: bool = True,
-        keep_trace: str | None = None) -> dict:
+        traced: bool, *, t_start: float, check_device: bool = True) -> dict:
     import jax
     import numpy as np
 
@@ -300,11 +294,13 @@ def run(root: pathlib.Path, workload: str, seed: int, seconds: float,
 
     trace_dir = tempfile.mkdtemp(prefix="chipbench-trace-") if traced \
         else None
-    served, window_s, rounds, part = serve_window(entry, seconds, 1,
-                                                  trace_dir)
+    served, window_s, rounds, part = serve_window(
+        entry, seconds, 1, trace_dir,
+        int(cell.mix["trace_rounds"]) if traced else 0)
     keep(served)
     step_ms = entry.step_ms()[warm_steps:]
     in_window = clock.compiles - compiles0
+    degraded = entry.degraded()
     log(f"window {window_s:.3f} s, {rounds} rounds, compiles inside "
         f"{in_window}")
     mem = [(d.memory_stats() or {}).get("peak_bytes_in_use") for d in cell_devs]
@@ -325,30 +321,33 @@ def run(root: pathlib.Path, workload: str, seed: int, seconds: float,
     log(f"reference over {sum(len(v) for v in kept.values())} images in "
         f"{time.perf_counter() - t:.3f} s; fewest CG iterations in a frame "
         f"{cg_iters}")
+    # every frame handed over is served, finite, at the configured
+    # accuracy: none shed, rejected or non-finite, and no step down the
+    # deadline ladder (which dials Newton and CG steps down)
     checks = {"image_rel_l2": {"value": gap,
                                "limit": float(cell.limits["image_rel_l2"])},
-              "nonfinite_frames": {"value": nonfinite, "limit": 0}}
+              "nonfinite_frames": {"value": nonfinite, "limit": 0},
+              "failed_frames": {"value": failed, "limit": 0},
+              "degraded_steps": {"value": degraded, "limit": 0}}
     correct = all(c["value"] <= c["limit"] for c in checks.values())
 
     if part is not None:
-        # per-layer metrics read the profiled start of the window
-        _, r, window_s = part
-        good = [s for s in good if s.frame <= r]
-        step_ms = step_ms[:r]
-        log(f"traced {r} rounds, {window_s:.3f} s")
+        # per-layer metrics read the profiled rounds of the window
+        r0, r, window_s = part
+        good = [s for s in good if r0 <= s.frame < r0 + r]
+        step_ms = step_ms[r0 - 1:r0 - 1 + r]
+        log(f"traced rounds {r0}..{r0 + r - 1}, {window_s:.3f} s")
     ctx = Context(cell=cell, chips=cell.chips, peak=peak, setup_s=setup_s,
                   window_s=window_s, served=good, step_ms=step_ms,
                   cg_iters=cg_iters)
     breakdown = None
     if traced:
-        if keep_trace:
-            shutil.copytree(trace_dir, keep_trace, dirs_exist_ok=True)
         try:
             ctx.trace = tracemod.load(trace_dir)
         finally:
             shutil.rmtree(trace_dir, ignore_errors=True)
         breakdown = stats.breakdown(ctx.trace)
-        by_code = stats.op_seconds(ctx.trace, lambda e: tracemod.opcode(e.name))
+        by_code = stats.op_seconds(ctx.trace, lambda e: e.code)
         log(f"device seconds by opcode: "
             f"{json.dumps(by_code.most_common(16))}")
         log(f"collectives per frame: "

@@ -34,7 +34,7 @@ def main(argv=None) -> int:
     ap.add_argument("--seeds", default="")
     ap.add_argument("--control-seeds", default="")
     args = ap.parse_args(argv)
-    bench.use_checkout_cache(ROOT)
+    bench.keep_every_program()
     import jax
 
     from chipbench import reference
@@ -42,6 +42,8 @@ def main(argv=None) -> int:
     from chipbench.traffic import make_traffic
     sys.path.insert(0, str(ROOT / "src"))
     from repro.core import Environment
+    from repro.core.runtime import use_compile_cache
+    use_compile_cache()
 
     cell = bench.load_cell(ROOT, args.workload)
     try:

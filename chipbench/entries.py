@@ -83,6 +83,11 @@ class ServiceEntry:
         """The program's own timer: wall ms of each tick."""
         return list(self.sched.tick_ms)
 
+    def degraded(self) -> int:
+        """Steps the scheduler took on its deadline ladder, each one
+        fewer Newton or CG steps or a narrower batch."""
+        return len(self.sched.events)
+
     def close(self):
         self.sched = self.sessions = None
 
@@ -120,6 +125,10 @@ class StreamEntry:
     def step_ms(self) -> list:
         """The program's own timer: ``LatencyReport.frame_ms``."""
         return list(self._frame_ms)
+
+    def degraded(self) -> int:
+        """``FrameStream`` has no deadline ladder."""
+        return 0
 
     def close(self):
         self.fs = self.carry = None

@@ -7,7 +7,7 @@ from chipbench.stats import exposed_collective_s
 
 def read(ctx):
     if ctx.trace is None or not ctx.frames or not any(
-            tr.is_collective(e.name)
+            tr.is_collective(e.code)
             for evs in ctx.trace.ops.values() for e in evs):
         return None
     devs = list(ctx.trace.ops)

@@ -1,8 +1,7 @@
-"""Device time of the frame program per served frame, averaged over the
-cell's chips.  The frame program is the program that ran longest on the
-device in the window (``XLA Modules`` on a TPU)."""
-
-import collections
+"""Device time of the programs the window runs, per served frame,
+averaged over the cell's chips: every program run (``XLA Modules`` on a
+TPU) inside the traced window is summed, so that a frame program split
+into several programs still counts whole."""
 
 
 def read(ctx):
@@ -10,11 +9,10 @@ def read(ctx):
     if t is None or not ctx.frames or not t.modules:
         return None
     lo, hi = t.window()
-    per = collections.Counter()
+    total = 0.0
     for evs in t.modules.values():
         for e in evs:
             s, f = max(e.start, lo), min(e.end, hi)
             if f > s:
-                per[e.name] += f - s
-    _, total = per.most_common(1)[0]
+                total += f - s
     return total / len(t.modules) / 1e6 / ctx.frames

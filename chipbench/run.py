@@ -32,14 +32,11 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
-    ap.add_argument("--keep-trace", metavar="DIR",
-                    help="also copy the raw profiler trace to DIR")
     args = ap.parse_args(argv)
-    bench.use_checkout_cache(ROOT)
+    bench.keep_every_program()
     try:
         result = bench.run(ROOT, args.workload, args.seed, args.seconds,
-                           bool(args.trace), t_start=T_START,
-                           keep_trace=args.keep_trace)
+                           bool(args.trace), t_start=T_START)
     except bench.BenchError as e:
         print(f"chipbench: {e}", file=sys.stderr, flush=True)
         return 2

@@ -73,7 +73,7 @@ def op_seconds(trace, key) -> collections.Counter:
     for evs in trace.ops.values():
         for e in evs:
             s, t = max(e.start, lo), min(e.end, hi)
-            if t > s and not tr.is_container(e.name):
+            if t > s and not tr.is_container(e.code):
                 out[key(e)] += (t - s) / 1e9
     return out
 
@@ -86,8 +86,8 @@ def collectives_per_frame(ctx) -> dict:
     kinds = collections.Counter()
     for evs in ctx.trace.ops.values():
         for e in evs:
-            if tr.is_collective(e.name) and lo <= e.start < hi:
-                kinds[tr.COLLECTIVE.match(e.name).group(1).lower()] += 1
+            if tr.is_collective(e.code) and lo <= e.start < hi:
+                kinds[tr.COLLECTIVE.match(e.code).group(1)] += 1
     n = len(ctx.trace.ops) * ctx.frames
     return {k: v / n for k, v in sorted(kinds.items())}
 
@@ -97,8 +97,8 @@ def exposed_collective_s(trace, device) -> float:
     operation ran on it, inside the window."""
     lo, hi = trace.window()
     evs = trace.ops.get(device, [])
-    coll = tr.union(tr.clip([e for e in evs if tr.is_collective(e.name)],
+    coll = tr.union(tr.clip([e for e in evs if tr.is_collective(e.code)],
                             lo, hi))
-    other = tr.clip([e for e in evs if not tr.is_collective(e.name)
-                     and not tr.is_container(e.name)], lo, hi)
+    other = tr.clip([e for e in evs if not tr.is_collective(e.code)
+                     and not tr.is_container(e.code)], lo, hi)
     return tr.length(tr.subtract(coll, other)) / 1e9

@@ -1,10 +1,14 @@
 """Read a ``jax.profiler`` trace into device operations and host spans.
 
 On a TPU every chip has a plane ``/device:TPU:<n>``; its line
-``XLA Ops`` holds one event per operation run, and ``XLA Modules`` one
-per program run.  On the CPU (used by the tests) there is no device
-plane: the operations are the host events that carry an ``hlo_op``
-stat, all counted as device 0.  Host spans are the benchmark's own
+``XLA Ops`` holds one event per operation run, named by the operation's
+HLO text (``%psum.3 = f32[] all-reduce(...), ...``), and ``XLA Modules``
+one per program run.  An operation is classified by the opcode in that
+text, never by its instruction name: ``lax.psum`` lowers to an
+``all-reduce`` named ``psum.N``.  On the CPU (used by the tests) there is
+no device plane: the operations are the host events that carry an
+``hlo_op`` stat, all counted as device 0, and their names hold no
+opcode.  Host spans are the benchmark's own
 ``jax.profiler.TraceAnnotation`` events, named ``chipbench.<what>``.
 Every time is in nanoseconds on the trace's one clock.
 """
@@ -18,7 +22,7 @@ import re
 
 COLLECTIVE = re.compile(
     r"^(all-reduce|all-gather|reduce-scatter|all-to-all|collective-permute"
-    r"|allreduce|all_reduce)", re.I)
+    r"|collective-broadcast|ragged-all-to-all)")
 # ops whose events span the ops they run (a TPU trace nests a while
 # loop's body inside the loop's own event)
 CONTAINERS = ("while", "conditional", "call")
@@ -30,6 +34,11 @@ class Event:
     start: float
     dur: float
     stats: tuple = ()
+    code: str = ""          # the HLO opcode; see :func:`hlo_opcode`
+
+    def __post_init__(self):
+        if not self.code:
+            object.__setattr__(self, "code", hlo_opcode(self.name))
 
     @property
     def end(self) -> float:
@@ -76,9 +85,35 @@ def opcode(name: str) -> str:
     return re.sub(r"\.\d+$", "", name)
 
 
+_OPCODE = re.compile(r"([a-z][a-z0-9_-]*)\(")
+
+
+def hlo_opcode(text: str) -> str:
+    """The opcode of an operation from its HLO text: ``all-reduce`` from
+    ``%psum.3 = f32[] all-reduce(f32[] %x), ...``.  The result shape may
+    be a tuple or carry a layout with parentheses, so it is skipped as a
+    balanced group or up to the first space.  Text without `` = `` (a
+    bare instruction name) gives the name without its number."""
+    if " = " not in text:
+        return opcode(op_name(text))
+    rest = text.split(" = ", 1)[1].lstrip()
+    if rest.startswith("("):
+        depth = 0
+        for i, ch in enumerate(rest):
+            depth += (ch == "(") - (ch == ")")
+            if depth == 0:
+                rest = rest[i + 1:]
+                break
+    else:
+        rest = rest.split(" ", 1)[1] if " " in rest else ""
+    m = _OPCODE.match(rest.lstrip())
+    return m.group(1) if m else opcode(op_name(text))
+
+
 def _events(line):
     for e in line.events:
-        yield Event(op_name(e.name), float(e.start_ns), float(e.duration_ns))
+        yield Event(op_name(e.name), float(e.start_ns), float(e.duration_ns),
+                    code=hlo_opcode(e.name))
 
 
 _DEVICE = re.compile(r"^/device:(?!CPU)[A-Z]+:(\d+)$")
@@ -176,12 +211,14 @@ def subtract(a, b) -> list:
     return out
 
 
-def is_collective(name: str) -> bool:
-    return bool(COLLECTIVE.match(name))
+def is_collective(code: str) -> bool:
+    """Whether an HLO opcode is a collective (its async start and done
+    included)."""
+    return bool(COLLECTIVE.match(code))
 
 
-def is_container(name: str) -> bool:
-    return opcode(name) in CONTAINERS
+def is_container(code: str) -> bool:
+    return code in CONTAINERS
 
 
 def gaps(busy, lo: float, hi: float) -> list:

@@ -20,9 +20,10 @@ TINY_CONFIG = {
 }
 MIXES = {
     "tiny-service": {"entry": "service", "scanners": 2, "bucket": 2,
-                     "movie_frames": 3, "check_frames": 2, "noise": 1e-4},
+                     "movie_frames": 3, "check_frames": 2, "noise": 1e-4,
+                     "trace_rounds": 2},
     "tiny-stream": {"entry": "stream", "scanners": 1, "movie_frames": 3,
-                    "check_frames": 2, "noise": 1e-4},
+                    "check_frames": 2, "noise": 1e-4, "trace_rounds": 3},
 }
 # the tiny program sits ~1e-5 from the reference on the CPU; a wrong
 # frame sits at 1e-1 or more

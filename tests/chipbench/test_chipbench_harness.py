@@ -7,6 +7,7 @@ import os
 import shutil
 import subprocess
 import sys
+import tempfile
 import time
 
 import numpy as np
@@ -14,7 +15,7 @@ import pytest
 
 from conftest import REPO, TINY_LIMIT, write_root
 
-from chipbench import bench, reference, trace_summary
+from chipbench import bench, reference
 
 
 def _run(root, cell, seed=2**31 + 11, seconds=0.2, traced=False):
@@ -34,12 +35,14 @@ def test_sound_run_is_correct(tiny_root, cell):
     assert r["checks"]["image_rel_l2"]["limit"] == TINY_LIMIT
 
 
-def test_traced_run_reads_per_layer_metrics(tiny_root, tmp_path, capsys):
-    kept = tmp_path / "kept-trace"
-    r = bench.run(tiny_root, "tiny.service", 2**31 + 13, 0.5, True,
-                  t_start=time.perf_counter(), check_device=False,
-                  keep_trace=str(kept))
+@pytest.mark.parametrize("seconds", [0.01, 0.5])
+def test_traced_run_reads_per_layer_metrics(tiny_root, seconds):
+    """The mix's trace_rounds rounds after the window's first are traced,
+    however short the window."""
+    r = bench.run(tiny_root, "tiny.service", 2**31 + 13, seconds, True,
+                  t_start=time.perf_counter(), check_device=False)
     assert r["correct"], r["checks"]
+    assert r["attempted"] >= 2 * (1 + 2)
     got = r["metrics"]
     assert {"step_ms_p50", "frame_roofline", "device_idle_pct"} <= set(got)
     # no collective runs on one device: the reader finds nothing to read
@@ -50,11 +53,30 @@ def test_traced_run_reads_per_layer_metrics(tiny_root, tmp_path, capsys):
     ops = r["breakdown"]["device_ops"]
     assert ops and all(isinstance(n, str) and v > 0 for n, v in ops)
     assert len(r["breakdown"]["idle_gaps"]) <= 10
-    # the kept trace can be read by hand
-    capsys.readouterr()
-    assert trace_summary.main([str(kept), "1"]) == 0
-    out = capsys.readouterr().out
-    assert "PLANE /host:CPU" in out and "chipbench.window" in out
+
+
+def test_serve_window_traces_a_fixed_count_of_rounds():
+    class Entry:
+        def __init__(self):
+            self.rounds = []
+
+        def round(self, f):
+            self.rounds.append(f)
+            return [f]
+
+    for seconds, want in ((0.0, [1, 2, 3, 4]), (0.05, None)):
+        e = Entry()
+        with tempfile.TemporaryDirectory() as d:
+            served, window_s, rounds, traced = bench.serve_window(
+                e, seconds, 1, d, trace_rounds=3)
+        assert traced[:2] == (2, 3) and traced[2] <= window_s
+        assert served == e.rounds and rounds == len(e.rounds)
+        assert e.rounds[:4] == [1, 2, 3, 4]
+        if want is not None:
+            assert e.rounds == want
+    e = Entry()
+    served, window_s, rounds, traced = bench.serve_window(e, 0.0, 1)
+    assert traced is None and e.rounds == [1]
 
 
 def test_cell_config_mix_and_metric_found_by_new_files(tmp_path):
@@ -83,6 +105,31 @@ def test_cell_config_mix_and_metric_found_by_new_files(tmp_path):
     r = _run(root, "tiny.new")
     assert r["correct"], r["checks"]
     assert r["metrics"]["frames_seen"]["value"] == r["attempted"]
+
+
+@pytest.mark.parametrize("fault", ["rejected", "shed", "degraded"])
+def test_failed_or_degraded_frames_are_not_correct(tiny_root, monkeypatch,
+                                                   fault):
+    """A frame shed, rejected, or served after a step down the deadline
+    ladder, past the compared rounds, still turns ``correct`` false."""
+    from chipbench import entries
+    orig = entries.ServiceEntry.round
+
+    def round_(self, f):
+        out = orig(self, f)
+        if f == 3 and fault != "degraded":
+            out[1] = entries.Served(out[1].scanner, f, failure=fault)
+        if f == 3 and fault == "degraded":
+            self.sched.events.append({"rung": 1})
+        return out
+
+    monkeypatch.setattr(entries.ServiceEntry, "round", round_)
+    r = bench.run(tiny_root, "tiny.service", 2**31 + 17, 0.0, True,
+                  t_start=time.perf_counter(), check_device=False)
+    assert not r["correct"]
+    assert r["checks"]["image_rel_l2"]["value"] <= TINY_LIMIT
+    key = "degraded_steps" if fault == "degraded" else "failed_frames"
+    assert r["checks"][key]["value"] == 1 > r["checks"][key]["limit"]
 
 
 def test_missing_files_are_errors(tiny_root):

@@ -62,11 +62,50 @@ def _ev(name, start, dur):
     return tr.Event(name, float(start), float(dur))
 
 
+# operations as a TPU trace names them: by their HLO text
+HLO = {
+    "%psum.3 = f32[] all-reduce(f32[] %x), channel_id=1, "
+    "replica_groups={{0,1,2,3}}, to_apply=%add": ("psum.3", "all-reduce"),
+    "%custom-call.1 = f32[8,768,768]{2,1,0:T(8,128)} custom-call(c64[8,768,"
+    "768]{2,1,0:T(8,128)} %args_0_.1), custom_call_target=\"X64SplitLow\"":
+        ("custom-call.1", "custom-call"),
+    "%while.12 = (s32[], f32[8]{0}) while((s32[], f32[8]{0}) %t), "
+    "condition=%c, body=%b": ("while.12", "while"),
+    "%all-gather-start.2 = (f32[2]{0}, f32[8]{0}) all-gather-start(f32[2]{0}"
+    " %p), dimensions={0}": ("all-gather-start.2", "all-gather-start"),
+    "%fusion.7 = c64[2,768,768]{2,1,0:T(8,128)(2,1)} fusion(c64[2,768,768]"
+    "{2,1,0:T(8,128)(2,1)} %a), kind=kLoop, calls=%fused":
+        ("fusion.7", "fusion"),
+    "%cg_update_pallas.5 = (f32[1,1]{1,0}, c64[8]{0}) custom-call(f32[8]{0} "
+    "%v), custom_call_target=\"tpu_custom_call\"":
+        ("cg_update_pallas.5", "custom-call"),
+    "psum_invariant.7": ("psum_invariant.7", "psum_invariant"),
+}
+
+
+@pytest.mark.parametrize("text", sorted(HLO))
+def test_operations_are_classified_by_their_hlo_opcode(text):
+    name, code = HLO[text]
+    assert tr.op_name(text) == name
+    assert tr.hlo_opcode(text) == code
+    assert tr.Event(tr.op_name(text), 0.0, 1.0,
+                    code=tr.hlo_opcode(text)).code == code
+    assert tr.is_collective(code) == code.startswith(("all-", "reduce-s"))
+    assert tr.is_container(code) == (code == "while")
+
+
+def _hlo(text, start, dur):
+    return tr.Event(tr.op_name(text), float(start), float(dur),
+                    code=tr.hlo_opcode(text))
+
+
 def _synthetic():
     """Device 0: compute 0-10, all-reduce 8-14 (4 exposed), compute
-    20-30; device 1: compute 0-5; window 0-40 (ns)."""
+    20-30, a psum (an all-reduce by its opcode) 31-33, all exposed;
+    device 1: compute 0-5; window 0-40 (ns)."""
+    psum = next(t for t in HLO if t.startswith("%psum"))
     ops = {0: [_ev("fusion.1", 0, 10), _ev("all-reduce.3", 8, 6),
-               _ev("fft.2", 20, 10)],
+               _ev("fft.2", 20, 10), _hlo(psum, 31, 2)],
            1: [_ev("fusion.1", 0, 5)]}
     return tr.Trace(ops, {}, [_ev("chipbench.window", 0, 40),
                               _ev("chipbench.step", 0, 16),
@@ -75,17 +114,48 @@ def _synthetic():
 
 def test_interval_reducers():
     t = _synthetic()
-    assert stats.busy(t, 0) == [(0, 14), (20, 30)]
-    assert stats.busy_s(t) == pytest.approx((24 + 5) / 2 / 1e9)
+    assert stats.busy(t, 0) == [(0, 14), (20, 30), (31, 33)]
+    assert stats.busy_s(t) == pytest.approx((26 + 5) / 2 / 1e9)
     assert stats.traced_window_s(t) == pytest.approx(40 / 1e9)
-    assert stats.exposed_collective_s(t, 0) == pytest.approx(4 / 1e9)
+    assert stats.exposed_collective_s(t, 0) == pytest.approx(6 / 1e9)
     assert stats.exposed_collective_s(t, 1) == 0
     b = stats.breakdown(t)
     assert b["device_ops"][0] == ["fusion.1", pytest.approx(15 / 1e9)]
-    # device 0 idles 14-20 (host fetching) and 30-40 (no span open)
-    assert b["idle_gaps"] == [["none", pytest.approx(10 / 1e9)],
-                              ["fetch", pytest.approx(6 / 1e9)]]
+    # device 0 idles 14-20 (host fetching), 33-40 (no span open), 30-31
+    assert b["idle_gaps"] == [["none", pytest.approx(7 / 1e9)],
+                              ["fetch", pytest.approx(6 / 1e9)],
+                              ["none", pytest.approx(1 / 1e9)]]
     assert tr.subtract([(0, 10)], [(2, 3), (5, 12)]) == [(0, 2), (3, 5)]
+
+
+def test_collective_readers_count_psums():
+    """Both collectives of the synthetic trace count, the one named
+    ``psum`` too: 6 ns exposed on device 0, none on device 1, over 2
+    frames; 2 all-reduces over 2 devices and 2 frames."""
+    import importlib
+    from types import SimpleNamespace
+    ctx = SimpleNamespace(trace=_synthetic(), frames=2)
+    assert stats.collectives_per_frame(ctx) == {"all-reduce": 0.5}
+    read = importlib.import_module(
+        "chipbench.metrics.collective_exposed_ms").read
+    assert read(ctx) == pytest.approx(1e3 * 6e-9 / 2 / 2)
+    by_code = stats.op_seconds(ctx.trace, lambda e: e.code)
+    assert by_code["all-reduce"] == pytest.approx(8e-9)
+
+
+def test_solve_device_ms_sums_every_program():
+    """A frame program split in two programs counts whole."""
+    import importlib
+    from types import SimpleNamespace
+    read = importlib.import_module("chipbench.metrics.solve_device_ms").read
+    win = [_ev("chipbench.window", 0, 100)]
+    one = tr.Trace({}, {0: [_ev("jit_frame(1)", 10, 60)]}, win)
+    two = tr.Trace({}, {0: [_ev("jit_a(1)", 10, 30), _ev("jit_b(2)", 40, 30)]},
+                   win)
+    ctx = SimpleNamespace(trace=one, frames=3)
+    whole = read(ctx)
+    ctx.trace = two
+    assert read(ctx) == pytest.approx(whole) == pytest.approx(60 / 1e6 / 3)
 
 
 def test_reducers_on_a_recorded_cpu_trace():
