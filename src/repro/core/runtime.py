@@ -142,6 +142,15 @@ def use_compile_cache() -> str:
     return path
 
 
+def span(name: str, **ids):
+    """A host span ``repro.<name>`` on the profiler's clock: a
+    ``jax.profiler.TraceAnnotation`` (about a microsecond when no
+    profiler runs).  Spans nest on the calling thread; ``ids`` (``sid``,
+    ``tick``, ``width``, ``frame``) are what the spans of one frame
+    share."""
+    return jax.profiler.TraceAnnotation(f"repro.{name}", **ids)
+
+
 def current_group(group=None) -> DeviceGroup:
     """Default-group resolution: explicit arg > ambient mesh > all devices.
 

@@ -87,7 +87,8 @@ def fft2(x, inverse: bool = False, centered: bool = False,
     plan lookup happens at trace time, so a jitted caller pays it once."""
     plan = plan_fft2(jnp.shape(x), jnp.result_type(x), inverse=inverse,
                      centered=centered, cache=cache)
-    return plan(x)
+    with jax.named_scope("lib.fft"):
+        return plan(x)
 
 
 # ---------------------------------------------------------------------------
@@ -241,4 +242,5 @@ def fft2_batched(x: SegmentedArray, inverse: bool = False,
     frame)."""
     plan = plan_fft2_batched(x, inverse=inverse, centered=centered,
                              cache=cache)
-    return plan(x)
+    with jax.named_scope("lib.fft"):
+        return plan(x)

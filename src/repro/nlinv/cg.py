@@ -1,7 +1,7 @@
 """Conjugate gradient on the (rho, chat) pytree (inner solver of eq. 3).
 
-lax.while_loop with max-iteration + relative-residual stopping.  Two
-bodies share the loop scaffolding:
+lax.while_loop with max-iteration + relative-residual stopping, under
+the device scope ``nlinv.cg``.  Two bodies share the loop scaffolding:
 
 ``cg``        the unfused baseline: every scalar product goes through
               ``dot`` (the distributed path passes the bound
@@ -31,6 +31,7 @@ from ..kernels.cg_fused import ops as _fused_ops
 from .operators import uaxpy, udot
 
 
+@jax.named_scope("nlinv.cg")
 def cg(A, rhs, x0, *, iters: int = 30, tol: float = 1e-6, dot=udot):
     """Solve A x = rhs, A SPD (normal operator + alpha I)."""
     r0 = uaxpy(-1.0, A(x0), rhs)
@@ -82,6 +83,7 @@ def _fused_xpby(r, p, beta):
                                            with_dot=False)[0], r, p)
 
 
+@jax.named_scope("nlinv.cg")
 def cg_fused(apply_pap, rhs, *, iters: int = 30, tol: float = 1e-6,
              rs_sum=None, x0=None):
     """Fused-hot-path CG.

@@ -35,14 +35,15 @@ def irgnm(ops, y, x0, x_ref=None, *, newton: int = 7, cg_iters: int = 30,
         # movies pass the (damped) previous frame instead — paper §3.2.
     alpha = jnp.asarray(alpha0, jnp.float32)
     for n in range(newton):
-        r = uaxpy(-1.0, ops.G(x), y)                       # y - G(x)
-        rhs = ops.DGH(x, r, channel_sum=channel_sum)
-        rhs = uaxpy(alpha, uaxpy(-1.0, x, x_ref), rhs)     # - a (x - ref)
-        A = lambda du: ops.normal(x, du, alpha, channel_sum=channel_sum)
-        dx = cg(A, rhs, jax.tree.map(jnp.zeros_like, x),
-                iters=cg_iters, dot=dot)
-        x = uaxpy(1.0, dx, x)
-        alpha = alpha * q
+        with jax.named_scope("nlinv.newton"):
+            r = uaxpy(-1.0, ops.G(x), y)                   # y - G(x)
+            rhs = ops.DGH(x, r, channel_sum=channel_sum)
+            rhs = uaxpy(alpha, uaxpy(-1.0, x, x_ref), rhs)  # - a (x - ref)
+            A = lambda du: ops.normal(x, du, alpha, channel_sum=channel_sum)
+            dx = cg(A, rhs, jax.tree.map(jnp.zeros_like, x),
+                    iters=cg_iters, dot=dot)
+            x = uaxpy(1.0, dx, x)
+            alpha = alpha * q
     return x
 
 
@@ -74,17 +75,19 @@ def irgnm_fused(ops, y, x0, x_ref=None, *, newton: int = 7,
     # is masked by construction, so masking y ONCE here makes every
     # residual mask-supported for arbitrary caller data (a no-op when y
     # is already sampled k-space) — exactness, not an assumption.
-    y = plane_mult(y, ops.mask)
+    with jax.named_scope("nlinv.mask"):
+        y = plane_mult(y, ops.mask)
     alpha = jnp.asarray(alpha0, jnp.float32)
     for n in range(newton):
-        pre = ops.precompute(x)
-        r = uaxpy(-1.0, ops.G_fused(x, c0=pre["c0"]), y)   # y - G(x), masked
-        rhs, _ = ops.DGH_fused(pre, r, reducer=reducer)
-        rhs = uaxpy(alpha, uaxpy(-1.0, x, x_ref), rhs)     # - a (x - ref)
-        pap = lambda p: ops.normal_pap(pre, p, alpha, reducer=reducer)
-        dx = cg_fused(pap, rhs, iters=cg_iters, rs_sum=rs_sum)
-        x = uaxpy(1.0, dx, x)
-        alpha = alpha * q
+        with jax.named_scope("nlinv.newton"):
+            pre = ops.precompute(x)
+            r = uaxpy(-1.0, ops.G_fused(x, c0=pre["c0"]), y)  # y - G(x)
+            rhs, _ = ops.DGH_fused(pre, r, reducer=reducer)
+            rhs = uaxpy(alpha, uaxpy(-1.0, x, x_ref), rhs)  # - a (x - ref)
+            pap = lambda p: ops.normal_pap(pre, p, alpha, reducer=reducer)
+            dx = cg_fused(pap, rhs, iters=cg_iters, rs_sum=rs_sum)
+            x = uaxpy(1.0, dx, x)
+            alpha = alpha * q
     return x
 
 

@@ -150,6 +150,7 @@ class Reconstructor:
                       cg_iters=self.cg_iters, channel_sum=csum, dot=dot)
         return u
 
+    @jax.named_scope("nlinv.image")
     def _frame_image(self, mask, fov, weight, u):
         """Crop/readout stage: solved ``u`` -> displayed image (the
         root-sum-of-squares channel combination)."""

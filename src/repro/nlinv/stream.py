@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core.runtime import span
 from ..lib.plan import default_cache
 from ..task import Executor, Pipeline, TaskGraph
 from .operators import sobolev_weight
@@ -71,7 +72,17 @@ def upload_frame(rec: "Reconstructor", y, mask):
     loop and the serving scheduler issue (always through the verbs,
     never raw device_put+specs).  ``y`` must already be channel-padded
     to the group size."""
-    return rec.put_frame(np.asarray(y)), rec.put_const(np.asarray(mask))
+    with span("nlinv.upload"):
+        return rec.put_frame(np.asarray(y)), rec.put_const(np.asarray(mask))
+
+
+def damper(damping: float):
+    """The jitted temporal-regularization reference ``u -> damping * u``
+    (device scope ``nlinv.damp``)."""
+    @jax.named_scope("nlinv.damp")
+    def damp(u):
+        return jax.tree.map(lambda a: damping * a, u)
+    return jax.jit(damp)
 
 
 class DoubleBuffer:
@@ -167,8 +178,7 @@ class FrameStream:
         self.damping = damping
         self.donate_carry = donate_carry
         self.last_carry = None      # {"u", "x_ref"} after run() (fenced)
-        self._damp = jax.jit(
-            lambda u: jax.tree.map(lambda a: damping * a, u))
+        self._damp = damper(damping)
 
     def run(self, y, masks, fov, *, weight=None, carry=None,
             report_path=None) -> tuple[jax.Array, LatencyReport]:
@@ -181,24 +191,25 @@ class FrameStream:
         ``donate_carry`` the passed-in buffers are donated to frame 0.
         """
         rec = self.recon
-        y = np.asarray(y)
-        F = y.shape[0]
-        g = y.shape[-1]
-        y = pad_channels(y, rec.comm.size, axis=1)
-        J = y.shape[1]
-        if weight is None:
-            weight = sobolev_weight(g)
+        with span("stream.prepare"):
+            y = np.asarray(y)
+            F = y.shape[0]
+            g = y.shape[-1]
+            y = pad_channels(y, rec.comm.size, axis=1)
+            J = y.shape[1]
+            if weight is None:
+                weight = sobolev_weight(g)
 
-        fov_d = rec.put_const(np.asarray(fov))
-        w_d = rec.put_const(np.asarray(weight))
-        if carry is None:
-            u = rec.init_carry(J, g)
-            # x_ref starts equal to u but must be a distinct buffer:
-            # both are donated to the solver every frame.
-            x_ref = jax.tree.map(lambda a: a + 0, u)
-        else:
-            u, x_ref = carry["u"], carry["x_ref"]
-        fn = rec.fn_donate_carry if self.donate_carry else rec.fn
+            fov_d = rec.put_const(np.asarray(fov))
+            w_d = rec.put_const(np.asarray(weight))
+            if carry is None:
+                u = rec.init_carry(J, g)
+                # x_ref starts equal to u but must be a distinct buffer:
+                # both are donated to the solver every frame.
+                x_ref = jax.tree.map(lambda a: a + 0, u)
+            else:
+                u, x_ref = carry["u"], carry["x_ref"]
+            fn = rec.fn_donate_carry if self.donate_carry else rec.fn
 
         cache = getattr(rec, "plan_cache", default_cache())
         run_start = cache.snapshot()
@@ -209,13 +220,15 @@ class FrameStream:
         for f in range(F):
             t0 = time.perf_counter()
             builds0 = cache.builds
-            yd, md = buf.take()
-            u, img = fn(yd, md, fov_d, w_d, u, x_ref)
-            # the solver is now in flight; upload frame f+1 behind it
-            if f + 1 < F:
-                buf.stage(f + 1)
-            x_ref = self._damp(u)
-            img.block_until_ready()
+            with span("stream.launch", frame=f):
+                yd, md = buf.take()
+                u, img = fn(yd, md, fov_d, w_d, u, x_ref)
+                # the solver is now in flight; upload frame f+1 behind it
+                if f + 1 < F:
+                    buf.stage(f + 1)
+                x_ref = self._damp(u)
+            with span("stream.wait", frame=f):
+                img.block_until_ready()
             frame_ms.append((time.perf_counter() - t0) * 1e3)
             # plans built during this frame: geometry setup (frame 0
             # traces the solver, building its fft/frame plans); the
@@ -223,17 +236,18 @@ class FrameStream:
             frame_builds.append(cache.builds - builds0)
             images.append(img)
 
-        self.last_carry = jax.block_until_ready(
-            {"u": u, "x_ref": x_ref})
-        # report per-RUN counter deltas, not the process-global
-        # cumulative stats — the artifact must describe this stream.
-        run = cache.delta(run_start)
-        report = LatencyReport(frame_ms, rec.comm.size, g, J,
-                               frame_plan_builds=frame_builds,
-                               plan_stats=run)
-        if report_path is not None:
-            report.save(report_path)
-        return jnp.stack(images), report
+        with span("stream.finish"):
+            self.last_carry = jax.block_until_ready(
+                {"u": u, "x_ref": x_ref})
+            # report per-RUN counter deltas, not the process-global
+            # cumulative stats — the artifact must describe this stream.
+            run = cache.delta(run_start)
+            report = LatencyReport(frame_ms, rec.comm.size, g, J,
+                                   frame_plan_builds=frame_builds,
+                                   plan_stats=run)
+            if report_path is not None:
+                report.save(report_path)
+            return jnp.stack(images), report
 
 
 def frame_graph(rec: "Reconstructor", take_upload, damp) -> TaskGraph:
@@ -303,8 +317,7 @@ class FramePipeline:
         self.retry = retry
         self.drop_failed = drop_failed
         self.last_carry = None      # {"u", "x_ref"} after run() (fenced)
-        self._damp = jax.jit(
-            lambda u: jax.tree.map(lambda a: damping * a, u))
+        self._damp = damper(damping)
 
     def run(self, y, masks, fov, *, weight=None, carry=None,
             report_path=None) -> tuple[jax.Array, LatencyReport]:

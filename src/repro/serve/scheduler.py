@@ -47,6 +47,7 @@ import time
 from collections import deque
 from typing import Any, Optional
 
+from ..core.runtime import span
 from ..nlinv.stream import latency_stats
 
 # Fault-injection hook on the tick boundary (``repro.ft.inject``
@@ -198,6 +199,9 @@ class StreamScheduler:
         self.rung = 0
         self.events: list[dict] = []    # every ladder transition
         self._breach = self._ok = 0     # consecutive-tick counters
+        # the scheduler's own clock for ``report()``'s throughput: the
+        # first accepted submit and the last delivery
+        self._first_submit = self._last_delivery = None
 
     # -- admission --------------------------------------------------------
     def open(self, client: str = "client", **meta) -> Session:
@@ -268,8 +272,12 @@ class StreamScheduler:
         if len(session.pending) >= self.config.queue_depth:
             session.rejected += 1
             return False
-        staged = self.workload.enqueue(session, item)
-        session.pending.append((staged, time.perf_counter()))
+        with span("serve.submit", sid=session.sid):
+            staged = self.workload.enqueue(session, item)
+        now = time.perf_counter()
+        if self._first_submit is None:
+            self._first_submit = now
+        session.pending.append((staged, now))
         return True
 
     # -- the tick ---------------------------------------------------------
@@ -301,6 +309,11 @@ class StreamScheduler:
             r = self.ticks % len(ready)
             ready = (ready[r:] + ready[:r])[:cap]
         width = self.config.bucket(len(ready))
+        with span("serve.tick", tick=self.ticks, width=width):
+            return self._step(ready, width)
+
+    def _step(self, ready: list, width: int) -> int:
+        """One Workload step over the ``ready`` sessions at ``width``."""
         batch = [(s, s.pending.popleft()) for s in ready]
         t0 = time.perf_counter()
         try:
@@ -334,6 +347,7 @@ class StreamScheduler:
                 s.poisoned += 1
             else:
                 s.latency_ms.append((t1 - t_submit) * 1e3)
+                self._last_delivery = t1
             if done:
                 self.close(s)
         if self.config.deadline_ms is not None:
@@ -414,7 +428,9 @@ class StreamScheduler:
     # -- accounting -------------------------------------------------------
     def report(self) -> dict:
         """Per-client latency/SLO table + aggregate throughput, on the
-        repo-wide ``latency_stats``."""
+        repo-wide ``latency_stats``.  ``aggregate.fps`` is frames
+        delivered over the time from the first submit to the last
+        delivery."""
         budget = self.config.budget_ms
         clients: dict[str, dict] = {}
         for s in itertools.chain(self.closed, self.waiting,
@@ -431,7 +447,10 @@ class StreamScheduler:
         frames = sum(len(s.latency_ms)
                      for s in itertools.chain(self.closed, self.waiting,
                                               self.sessions.values()))
-        wall = sum(self.tick_ms)
+        # frames over the scheduler's clock from the first submit to the
+        # last delivery: host time between ticks counts
+        wall = (0.0 if self._last_delivery is None
+                else (self._last_delivery - self._first_submit) * 1e3)
         # error accounting: "slow" (latency columns) vs "failing" (these)
         ft = {
             "step_faults": self.step_faults,

@@ -28,10 +28,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core.runtime import span
 from ..ft.remesh import migrate_carry, pad_rows
 from ..nlinv.operators import sobolev_weight
 from ..nlinv.recon import Reconstructor, pad_channels
-from ..nlinv.stream import upload_frame
+from ..nlinv.stream import damper, upload_frame
 from ..task import Executor, TaskGraph
 from .scheduler import Rejected, Session, Workload
 
@@ -73,8 +74,7 @@ class NlinvStreamWorkload(Workload):
         self.rec = rec
         self.damping = damping
         self._exec = Executor(retry=retry)
-        self._damp = jax.jit(
-            lambda u: jax.tree.map(lambda a: damping * a, u))
+        self._damp = damper(damping)
         self._geom = None            # (J_padded, grid), pinned by 1st open
         self._fov_d = self._w_d = None
         # persistent stacked carry: (sids tuple, u_stack, x_ref_stack),
@@ -92,6 +92,9 @@ class NlinvStreamWorkload(Workload):
         self._health_jit = None
         self.quarantined = 0         # total quarantine events
         self.remeshes = 0            # survivor-group migrations
+        # stack churn: 0 in the steady state of a stable ready set
+        self.restacks = 0            # carry stacks rebuilt
+        self.spills = 0              # stacks written back to sessions
 
     # -- degradation ladder (scheduler deadline enforcement) --------------
     @property
@@ -112,6 +115,8 @@ class NlinvStreamWorkload(Workload):
     def counters(self) -> dict:
         return {"retried_tasks": self._exec.retried,
                 "quarantined": self.quarantined,
+                "restacks": self.restacks,
+                "spills": self.spills,
                 "remeshes": self.remeshes}
 
     # -- session lifecycle ------------------------------------------------
@@ -159,6 +164,7 @@ class NlinvStreamWorkload(Workload):
             return
         sids, ub, xb = self._stack
         self._stack = None
+        self.spills += 1
         for i, sid in enumerate(sids):
             s = self._by_sid.get(sid)
             if s is None or not keep(sid):
@@ -177,13 +183,15 @@ class NlinvStreamWorkload(Workload):
         else:
             # membership or width changed: write everyone's carry back
             # to their session BEFORE the new map is installed
-            self._spill()
-            # pad the launch to the bucket width by replicating the
-            # last session's row (vmap rows are independent; padded
-            # rows are computed and discarded)
-            rows = sessions + [sessions[-1]] * (width - B)
-            ub = stack_carries([s.state["u"] for s in rows])
-            xb = stack_carries([s.state["x_ref"] for s in rows])
+            with span("serve.restack", width=width):
+                self._spill()
+                self.restacks += 1
+                # pad the launch to the bucket width by replicating the
+                # last session's row (vmap rows are independent; padded
+                # rows are computed and discarded)
+                rows = sessions + [sessions[-1]] * (width - B)
+                ub = stack_carries([s.state["u"] for s in rows])
+                xb = stack_carries([s.state["x_ref"] for s in rows])
         pads = [item for _, item in batch]
         pads += [pads[-1]] * (width - B)
         # One tick is one task graph: the stack of the already-uploaded
@@ -213,26 +221,28 @@ class NlinvStreamWorkload(Workload):
         # CG residual norm NaN, its `rs > thresh` guard False — the
         # solve degenerates to du = 0 and would silently deliver a
         # stale image; the only honest outcome is a Rejected frame.
-        ok = np.asarray(self._health(ub, imgb, vals["yb"]))
-        out = []
-        for i in range(width):
-            if bool(ok[i]):
+        with span("serve.health"):
+            ok = np.asarray(self._health(ub, imgb, vals["yb"]))
+        with span("serve.deliver"):
+            out = []
+            for i in range(width):
+                if bool(ok[i]):
+                    if i < B:
+                        out.append((imgb[i], False))
+                    continue
+                # quarantine row i: re-initialize its carry slice in place
+                # (rows are vmap-independent — every other client's result
+                # is bitwise what it would have been without the poison).
+                # Padded rows (i >= B) replicate the last session and must
+                # be reset too, or the spill would hand it a poisoned carry.
+                ub, xb = self._reset_row(ub, xb, i)
                 if i < B:
-                    out.append((imgb[i], False))
-                continue
-            # quarantine row i: re-initialize its carry slice in place
-            # (rows are vmap-independent — every other client's result
-            # is bitwise what it would have been without the poison).
-            # Padded rows (i >= B) replicate the last session and must
-            # be reset too, or the spill would hand it a poisoned carry.
-            ub, xb = self._reset_row(ub, xb, i)
-            if i < B:
-                self.quarantined += 1
-                out.append((Rejected("non-finite frame output; client "
-                                     "quarantined, carry re-initialized"),
-                            False))
-        self._stack = (sids + (sids[-1],) * (width - B), ub, xb)
-        self._by_sid = {s.sid: s for s in sessions}
+                    self.quarantined += 1
+                    out.append((Rejected("non-finite frame output; client "
+                                         "quarantined, carry re-initialized"),
+                                False))
+            self._stack = (sids + (sids[-1],) * (width - B), ub, xb)
+            self._by_sid = {s.sid: s for s in sessions}
         # NLINV streams are long-lived: never done from inside a tick
         return out
 
@@ -240,6 +250,7 @@ class NlinvStreamWorkload(Workload):
         """All-finite per batch row (carry, image, acquisition), fused
         into one jitted program."""
         if self._health_jit is None:
+            @jax.named_scope("serve.health")
             def fn(u, img, y):
                 ok = None
                 for a in jax.tree.leaves(u) + [img, y]:

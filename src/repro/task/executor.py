@@ -12,8 +12,9 @@ the Newton/CG solve of ``f``, the crop of ``f-1`` — are all in flight
 on the device queue at once; the per-frame host fence of the old
 two-stage engine (the pipeline bubble) is gone.
 
-``Executor``  runs one graph: validate, toposort, dispatch each task,
-              record per-task host (dispatch) time in ``trace``.
+``Executor``  runs one graph: validate, toposort, dispatch each task
+              inside a ``repro.task.<name>`` profiler span, keep the
+              latest tasks' host (dispatch) time in ``trace``.
 ``Pipeline``  the rolling form for streams: ``push`` one graph per
               frame/tick; at most ``inflight`` pushed steps stay
               unfenced — pushing past that retires (fences) the oldest,
@@ -36,6 +37,7 @@ from typing import Any, Mapping, Sequence
 
 import jax
 
+from ..core.runtime import span
 from .graph import TaskGraph
 
 # Fault-injection hook on task dispatch (``repro.ft.inject`` installs
@@ -60,7 +62,8 @@ class Executor:
     """Dispatch a :class:`TaskGraph` in dependency order.
 
     ``run`` returns the produced values.  With ``fence=True`` (default)
-    the returned values are materialized (``jax.block_until_ready``);
+    the returned values are materialized (``jax.block_until_ready``,
+    inside a ``repro.task.fence`` span);
     ``fence=False`` leaves them in flight — the :class:`Pipeline` uses
     that to keep several frames on the device queue at once.
 
@@ -80,10 +83,14 @@ class Executor:
     {'a': 1}
     >>> [r.name for r in ex.trace]
     ['one']
+
+    ``trace`` keeps the last 1024 runs: a service's executor runs tasks
+    for as long as the service lives.  Each dispatch is also a
+    ``repro.task.<name>`` span in a profiler trace.
     """
 
     def __init__(self, *, retry=None, retryable=()):
-        self.trace: list[TaskRun] = []
+        self.trace: deque[TaskRun] = deque(maxlen=1024)
         self.retry = retry
         self.retryable = tuple(retryable)
         self.retried = 0    # successful re-dispatches, lifetime
@@ -123,7 +130,8 @@ class Executor:
         for t in order:
             args = [values[v] for v in t.inputs]
             t0 = time.perf_counter()
-            res, tries = self._dispatch(t, args)
+            with span(f"task.{t.name}"):
+                res, tries = self._dispatch(t, args)
             self.trace.append(TaskRun(
                 t.name, t.kind, (time.perf_counter() - t0) * 1e3,
                 retries=tries))
@@ -140,7 +148,10 @@ class Executor:
         produced = {v: values[v] for v in graph.values()}
         out = (produced if outputs is None
                else {v: values[v] for v in outputs})
-        return jax.block_until_ready(out) if fence else out
+        if not fence:
+            return out
+        with span("task.fence"):
+            return jax.block_until_ready(out)
 
 
 class Pipeline:

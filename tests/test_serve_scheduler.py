@@ -113,6 +113,33 @@ def test_report_latency_slo_and_single_sample_guard():
     assert rep["aggregate"]["frames"] == 1 and rep["aggregate"]["ticks"] == 1
 
 
+def test_report_fps_counts_host_time_between_ticks(monkeypatch):
+    """Aggregate fps is frames over the time from the first submit to
+    the last delivery, host time between ticks included."""
+    import types
+
+    from repro.serve import scheduler
+    now = [0.0]
+    monkeypatch.setattr(scheduler, "time",
+                        types.SimpleNamespace(perf_counter=lambda: now[0]))
+
+    class Slow(StubWorkload):
+        def step(self, batch, width):
+            now[0] += 0.010                # 10 ms per tick
+            return super().step(batch, width)
+
+    sched = StreamScheduler(Slow())
+    s = sched.open("a")
+    for i in range(4):
+        sched.submit(s, i)
+        sched.tick()
+        now[0] += 0.040                    # 40 ms of host work between
+    agg = sched.report()["aggregate"]
+    # first submit at 0 ms, last delivery at 3 * 50 + 10 = 160 ms
+    assert agg["frames"] == 4 and agg["fps"] == round(4 / 0.160, 2)
+    assert agg["tick"]["mean_ms"] == pytest.approx(10.0)
+
+
 def test_latency_stats_single_sample_guard():
     s = latency_stats([7.25])
     assert s["jitter_ms"] == 0.0
