@@ -69,9 +69,8 @@ DAMPING = 0.9
 # f32 solve itself sits about 2e-3 (relative L2) from an f64 solve of
 # the same frame (CPU, grid 64, 7 Newton x 20 CG steps): truncated CG at
 # small regularization amplifies rounding.  A change of summation order
-# (vmap over clients, the coil sum split across chips) may move the
-# result by as much, so the bound is 5x that, far below what a wrong
-# kernel gives.
+# (the coil sum split across chips) may move the result by as much, so
+# the bound is 5x that, far below what a wrong kernel gives.
 RTOL = 1e-2
 
 # the kernel specs the frame program traces through

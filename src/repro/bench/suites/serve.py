@@ -9,7 +9,8 @@ worst per-client p95 (``extra.client_p95_ms`` — the column
 steady-state frames/sec of the batched scheduler vs the same K streams
 solved one-at-a-time (``FrameStream`` per client), same machine, same
 run — plus the max relative error between the two answers (must be
-bitwise-comparable; the batched program is the vmapped same math).
+bitwise-comparable; the batched program runs the unbatched frame body
+on each row in turn).
 """
 
 from __future__ import annotations
@@ -27,9 +28,8 @@ from ...nlinv.stream import FrameStream, latency_stats
 from ...serve import NlinvStreamWorkload, ServeConfig, StreamScheduler
 from ..registry import scenario
 
-# newton/cg deep enough to be collective-bound: the batched win is the
-# amortized per-iteration rendezvous, so a too-shallow solve understates
-# it and makes the A/B flaky
+# newton/cg deep enough that a frame's device work, not its launch,
+# sets the time, as it does at the paper's size
 PARAMS = {"tiny": dict(n=16, J=4, newton=3, cg=8, frames=5, clients=4),
           "paper": dict(n=32, J=8, newton=4, cg=10, frames=6, clients=4)}
 

@@ -176,14 +176,24 @@ class Reconstructor:
 
     # -- the batched frame program (serving layer: B clients, one launch) -
     def _frame_batched(self, y, mask, fov, weight, x0, x_ref):
-        """B independent frame solves in one SPMD program: vmap the
-        shard-local body over a leading client-batch dim.  All verbs in
-        ``_frame`` (windowed channel sum, piggybacked scalars, vdot) are
-        vmap-safe, so the collectives of B solves coalesce into one
-        rendezvous each — the amortization the multi-stream service is
-        built on."""
-        return jax.vmap(self._frame, in_axes=(0, 0, None, None, 0, 0))(
-            y, mask, fov, weight, x0, x_ref)
+        """B independent frame solves in one SPMD program over a leading
+        client-batch dim (``fov``/``weight`` shared by every row).  The
+        rows run one after another through the unbatched frame body, and
+        each row's result is written back into the carry stack it was
+        read from, so a donated ``x0`` holds the output and the solve's
+        working memory is one row's at any width."""
+        def row(i, t):
+            return jax.tree.map(lambda a: a[i], t)
+
+        def solve_row(i, carry):
+            u, img = carry
+            ui, img_i = self._frame(y[i], mask[i], fov, weight,
+                                    row(i, u), row(i, x_ref))
+            u = jax.tree.map(lambda s, r: s.at[i].set(r), u, ui)
+            return u, img.at[i].set(img_i)
+
+        return jax.lax.fori_loop(0, y.shape[0], solve_row,
+                                 (x0, jnp.zeros_like(x0["rho"])))
 
     def _build_batched(self, donate: bool):
         clone = Policy.CLONE

@@ -2,10 +2,11 @@
 
 :class:`NlinvStreamWorkload` — N concurrent real-time NLINV streams.
 Independent clients' Newton solves are stacked on a leading batch dim of
-the ``(rho, chat)`` carry pytree and solved in ONE SPMD launch
-(``Reconstructor.fn_batched``): the per-iteration collectives of B
-solves coalesce into one rendezvous each, which is where the batching
-win comes from.  Two invariants keep the tick cheap:
+the ``(rho, chat)`` carry pytree and solved in ONE SPMD launch per tick
+(``Reconstructor.fn_batched``), whose rows run one after another
+through the unbatched frame body: batching saves the per-launch host
+work of B programs, and each row costs what the unbatched frame costs.
+Two invariants keep the tick cheap:
 
   * the stacked carry is PERSISTENT — while the ready set is stable
     (the steady state of K clients streaming) the carry never leaves
@@ -187,8 +188,8 @@ class NlinvStreamWorkload(Workload):
                 self._spill()
                 self.restacks += 1
                 # pad the launch to the bucket width by replicating the
-                # last session's row (vmap rows are independent; padded
-                # rows are computed and discarded)
+                # last session's row (rows are independent; padded rows
+                # are computed and discarded)
                 rows = sessions + [sessions[-1]] * (width - B)
                 ub = stack_carries([s.state["u"] for s in rows])
                 xb = stack_carries([s.state["x_ref"] for s in rows])
@@ -231,8 +232,8 @@ class NlinvStreamWorkload(Workload):
                         out.append((imgb[i], False))
                     continue
                 # quarantine row i: re-initialize its carry slice in place
-                # (rows are vmap-independent — every other client's result
-                # is bitwise what it would have been without the poison).
+                # (rows are independent — every other client's result is
+                # bitwise what it would have been without the poison).
                 # Padded rows (i >= B) replicate the last session and must
                 # be reset too, or the spill would hand it a poisoned carry.
                 ub, xb = self._reset_row(ub, xb, i)

@@ -1,8 +1,9 @@
 """The serving layer (ISSUE 7): scheduler admission/backpressure/
 bucketing on a stub workload, SlotPool reclamation, the double-buffer
 helper, latency_stats guards, and batched-vs-sequential NLINV parity
-through the real scheduler on 1 (in-process) and 4 (subprocess)
-devices — including mixed per-client frame phases."""
+through the real scheduler on 1 and 4 (subprocess) devices — including
+mixed per-client frame phases — and the batched program's rows (each
+the unbatched frame's answer, one row's working memory at any width)."""
 
 import numpy as np
 import pytest
@@ -246,3 +247,79 @@ def test_scheduler_parity_1dev():
 
 def test_scheduler_parity_4dev():
     _run_parity(4)
+
+
+# ---------------------------------------------------------------------------
+# the batched program: rows solved one after another, in place
+# ---------------------------------------------------------------------------
+
+BATCH_ROWS = """
+import jax.numpy as jnp
+from repro.core import Environment
+from repro.nlinv import phantom
+from repro.nlinv.operators import sobolev_weight
+from repro.nlinv.recon import Reconstructor
+
+comm = Environment().subgroup({ndev})
+rec = Reconstructor(comm, newton=2, cg_iters=4, channel_sum="crop")
+B = 2
+datas = [phantom.make_dataset(n=16, ncoils=4, nspokes=7, frames=1, seed=s)
+         for s in range(B)]
+g = datas[0]["grid"]
+fov, w = jnp.asarray(datas[0]["fov"]), jnp.asarray(sobolev_weight(g))
+# distinct carries per row, so a row mix-up cannot pass
+us = []
+for b in range(B):
+    u = rec.init_carry(4, g)
+    us.append({{"rho": u["rho"] * (1.0 + 0.1 * b),
+               "chat": u["chat"] + 0.01 * (b + 1)}})
+stack = lambda xs: jax.tree.map(lambda *a: jnp.stack(a), *xs)
+y = jnp.stack([jnp.asarray(d["y"][0]) for d in datas])
+m = jnp.stack([jnp.asarray(d["masks"][0]) for d in datas])
+ub, imgb = rec.fn_batched(B)(y, m, fov, w, stack(us), stack(us))
+rel = lambda a, b: (np.abs(np.asarray(a) - np.asarray(b)).max()
+                    / max(np.abs(np.asarray(b)).max(), 1e-30))
+for b in range(B):
+    u1, img1 = rec.fn(y[b], m[b], fov, w, us[b], us[b])
+    errs = [rel(imgb[b], img1)] + [rel(ub[k][b], u1[k]) for k in u1]
+    check(f"row {{b}} matches the unbatched frame ({{max(errs):.2e}})",
+          max(errs) < 1e-6)
+"""
+
+
+@pytest.mark.parametrize("ndev", [1, 4])
+def test_batched_rows_match_unbatched_frame(ndev):
+    """Each row of ``fn_batched(2)`` is the unbatched frame program's
+    answer on that row, on one device and on a 4-device group."""
+    out = run_with_devices(BATCH_ROWS.format(ndev=ndev), ndev)
+    assert "FAIL" not in out
+
+
+BATCH_TEMPS = """
+import jax.numpy as jnp
+from repro.core import Environment
+from repro.nlinv.recon import Reconstructor
+
+comm = Environment().subgroup({ndev})
+rec = Reconstructor(comm, newton=2, cg_iters=4, channel_sum="crop")
+S, c64, g, J = jax.ShapeDtypeStruct, jnp.complex64, 32, 4
+temps = {{}}
+for B in (1, 4):
+    u = {{"rho": S((B, g, g), c64), "chat": S((B, J, g, g), c64)}}
+    args = (S((B, J, g, g), c64), S((B, g, g), jnp.bool_),
+            S((g, g), jnp.float32), S((g, g), jnp.float32), u, u)
+    comp = rec.fn_batched(B, donate=True).lower(*args).compile()
+    temps[B] = comp.memory_analysis().temp_size_in_bytes
+check(f"width-4 temporaries {{temps[4]}} B against width 1's {{temps[1]}} B",
+      temps[4] < 1.5 * temps[1])
+"""
+
+
+@pytest.mark.parametrize("ndev", [1, 4])
+def test_batched_program_holds_one_rows_temporaries(ndev):
+    """The rows run one after another and write back into the donated
+    carry stack, so the solve's working memory is one row's: a width-4
+    program needs about a width-1 program's temporaries (vectorized
+    rows need about four times as much)."""
+    out = run_with_devices(BATCH_TEMPS.format(ndev=ndev), ndev)
+    assert "FAIL" not in out

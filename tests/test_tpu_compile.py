@@ -5,9 +5,9 @@ tiling rules, VMEM limits, SMEM layouts under ``vmap``.  Here each
 Pallas entry is lowered with ``interpret=False`` and compiled for one
 chip of a *described* ``v5e:2x2`` topology — no accelerator is needed,
 only the TPU compiler that ships with jaxlib.  Shapes are the paper's
-problem (grid 768, J=8 coils); the batched cases add the leading client
-dim that ``Reconstructor.fn_batched`` puts on every operand via
-``jax.vmap``.
+problem (grid 768, J=8 coils); the batched cases compile each kernel
+under ``jax.vmap`` with a leading batch dim, the batching rule the
+kernels document.
 
 The topology is described inside a fixture, never at import: several
 test workers import this file, and only the one that runs it may load
