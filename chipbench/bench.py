@@ -2,8 +2,11 @@
 
 A cell (``workloads`` entry of ``BENCHMARK.json``) names a configuration
 (its ``file``), a traffic mix (``chipbench/traffic/<mix>.json``) and the
-chips it needs; its correctness limits are ``chipbench/limits/<cell>.json``
-and each metric is read by ``chipbench/metrics/<metric>.py``.  A run:
+chips it needs; the configuration names its problem
+(``chipbench/problems/<problem>.py``: traffic generator, plain reference,
+work count and the program calls the entries drive); its correctness
+limits are ``chipbench/limits/<cell>.json`` and each metric is read by
+``chipbench/metrics/<metric>.py``.  A run:
 
   1. finds the cell's files, then a TPU with enough chips (or fails);
   2. makes the traffic from the seed, builds the user entry and serves
@@ -14,7 +17,8 @@ and each metric is read by ``chipbench/metrics/<metric>.py``.  A run:
      window ends with it); a traced run profiles the mix's
      ``trace_rounds`` rounds that follow the window's first round;
   4. reads the peak device memory, frees the program, and compares the
-     served images of the first rounds with the plain reference;
+     served images of the first rounds with the problem's plain
+     reference;
   5. reads the metrics of the cell (end-to-end ones, or with ``trace``
      the per-layer ones from the profiler trace of the window) and
      returns the result line.
@@ -57,6 +61,7 @@ class Cell:
     name: str
     chips: int
     cfg: dict
+    problem: object         # the module chipbench/problems/<problem>.py
     mix: dict
     limits: dict
     end_to_end: list
@@ -77,9 +82,12 @@ def load_cell(root: pathlib.Path, name: str) -> Cell:
                 None)
     if conf is None:
         raise BenchError(f"no config {cell['config']!r} in BENCHMARK.json")
+    cfg = json.loads((root / conf["file"]).read_text())
+    if "problem" not in cfg:
+        raise BenchError(f"config {conf['file']} names no problem")
     return Cell(
-        name=name, chips=int(cell["chips"]),
-        cfg=json.loads((root / conf["file"]).read_text()),
+        name=name, chips=int(cell["chips"]), cfg=cfg,
+        problem=load_problem(root, cfg["problem"]),
         mix=json.loads((root / "chipbench" / "traffic"
                         / f"{cell['traffic']}.json").read_text()),
         limits=json.loads((root / "chipbench" / "limits"
@@ -88,16 +96,29 @@ def load_cell(root: pathlib.Path, name: str) -> Cell:
         per_layer=[m for m in bench["per_layer"] if _listed(m, name)])
 
 
+def _load_module(path: pathlib.Path, kind: str, name: str):
+    spec = importlib.util.spec_from_file_location(
+        f"chipbench_{kind}_" + name.replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def load_reader(root: pathlib.Path, metric: str):
     """The ``read(ctx)`` function of ``chipbench/metrics/<metric>.py``."""
     path = root / "chipbench" / "metrics" / f"{metric}.py"
     if not path.is_file():
         raise BenchError(f"no reader for metric {metric!r} ({path})")
-    spec = importlib.util.spec_from_file_location(
-        "chipbench_metric_" + metric.replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load_module(path, "metric", metric).read
+
+
+def load_problem(root: pathlib.Path, problem: str):
+    """The module ``chipbench/problems/<problem>.py`` (its interface is in
+    ``chipbench/problems/nlinv.py``'s docstring)."""
+    path = root / "chipbench" / "problems" / f"{problem}.py"
+    if not path.is_file():
+        raise BenchError(f"no problem {problem!r} ({path})")
+    return _load_module(path, "problem", problem)
 
 
 def load_peak(root: pathlib.Path, kind: str) -> dict:
@@ -152,8 +173,13 @@ class Context:
     window_s: float
     served: list            # the window's frames delivered and finite
     step_ms: list           # the program's own step timer, window only
-    cg_iters: int           # fewest CG iterations of a compared frame
+    cg_iters: int           # least work count (NLINV: CG iterations) of
+                            # a compared frame, by the reference
     trace: object = None    # trace.Trace of the window (traced runs)
+
+    @property
+    def problem(self):
+        return self.cell.problem
 
     @property
     def latencies(self) -> list:
@@ -176,19 +202,28 @@ def _cache_entries(path) -> list:
 
 
 def serve_window(entry, seconds: float, first_round: int, trace_dir=None,
-                 trace_rounds: int = 0):
+                 trace_rounds: int = 0, round_ms=None):
     """Rounds until ``seconds`` have passed.  With ``trace_dir``, the
     ``trace_rounds`` rounds after the window's first round run under the
     profiler (a fixed count, so that every traced run reads the same
     work; a trace of four chips holds about a million operations a
     second).  Returns (served, window_s, rounds, traced), ``traced`` being
-    the (first round, rounds, seconds) of the profiled part, or None."""
+    the (first round, rounds, seconds) of the profiled part, or None; the
+    wall ms of each round are appended to ``round_ms`` if it is given."""
     import jax
     served, f, traced = [], first_round, None
+    round_ms = [] if round_ms is None else round_ms
+
+    def one_round():
+        nonlocal f
+        t = time.perf_counter()
+        served.extend(entry.round(f))
+        round_ms.append((time.perf_counter() - t) * 1e3)
+        f += 1
+
     t0 = time.perf_counter()
     if trace_dir is not None:
-        served.extend(entry.round(f))
-        f += 1
+        one_round()
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0
         jax.profiler.start_trace(trace_dir, profiler_options=opts)
@@ -196,35 +231,29 @@ def serve_window(entry, seconds: float, first_round: int, trace_dir=None,
             t1 = time.perf_counter()
             with jax.profiler.TraceAnnotation("chipbench.window"):
                 for _ in range(trace_rounds):
-                    served.extend(entry.round(f))
-                    f += 1
+                    one_round()
             traced = (f - trace_rounds, trace_rounds, time.perf_counter() - t1)
         finally:
             jax.profiler.stop_trace()
     while f == first_round or time.perf_counter() - t0 < seconds:
-        served.extend(entry.round(f))
-        f += 1
+        one_round()
     return served, time.perf_counter() - t0, f - first_round, traced
 
 
 def compare(cell: Cell, traffic: dict, kept: dict, device):
     """Worst relative L2 gap of every scanner's served images of rounds
-    0..check_frames against the plain reference's chain of the same
-    scanner (a missing or failed image counts as an infinite gap), and
-    the fewest CG iterations the reference ran for one of those frames."""
-    from . import reference
-    cfg = cell.cfg
+    0..check_frames against the problem's plain reference chain of the
+    same scanner (a missing or failed image counts as an infinite gap),
+    and the least work count (NLINV: CG iterations) the reference gave
+    one of those frames."""
+    from .reference import rel_l2
     check = int(cell.mix["check_frames"])
     gaps, iters = [], []
     for i in range(len(traffic["movies"])):
         frames = kept.get(i, {})
-        mv = traffic["movies"][i]
-        ref, its = reference.movie(mv["y"], mv["masks"], traffic["fov"],
-                              newton=int(cfg["newton"]),
-                              cg_iters=int(cfg["cg_iters"]),
-                              damping=float(cfg["assumed"]["damping"]),
-                              frames=check + 1, device=device)
-        gaps += [reference.rel_l2(frames[f], ref[f])
+        ref, its = cell.problem.reference_movie(cell.cfg, traffic, i,
+                                                check + 1, device)
+        gaps += [rel_l2(frames[f], ref[f])
                  if frames.get(f) is not None else float("inf")
                  for f in range(check + 1)]
         iters += its
@@ -241,7 +270,6 @@ def run(root: pathlib.Path, workload: str, seed: int, seconds: float,
     from . import stats
     from . import trace as tracemod
     from .entries import ENTRIES
-    from .traffic import make_traffic
 
     cell = load_cell(root, workload)
     readers = {m["name"]: load_reader(root, m["name"])
@@ -268,12 +296,13 @@ def run(root: pathlib.Path, workload: str, seed: int, seconds: float,
     cell_devs = list(comm.mesh.devices.flat)
 
     t = time.perf_counter()
-    traffic = make_traffic(cell.cfg, cell.mix, seed)
+    traffic = cell.problem.make_traffic(cell.cfg, cell.mix, seed)
     log(f"traffic: {len(traffic['movies'])} scanner(s) x "
         f"{cell.mix['movie_frames']} distinct frames of "
-        f"{traffic['movies'][0]['y'].shape[1:]} made in "
+        f"{cell.problem.describe(traffic)} made in "
         f"{time.perf_counter() - t:.3f} s")
-    entry = ENTRIES[cell.mix["entry"]](cell.cfg, cell.mix, traffic, comm)
+    entry = ENTRIES[cell.mix["entry"]](cell.cfg, cell.mix, traffic, comm,
+                                       cell.problem)
     check = int(cell.mix["check_frames"])
     kept = collections.defaultdict(dict)
 
@@ -294,15 +323,18 @@ def run(root: pathlib.Path, workload: str, seed: int, seconds: float,
 
     trace_dir = tempfile.mkdtemp(prefix="chipbench-trace-") if traced \
         else None
+    round_ms = []
     served, window_s, rounds, part = serve_window(
         entry, seconds, 1, trace_dir,
-        int(cell.mix["trace_rounds"]) if traced else 0)
+        int(cell.mix["trace_rounds"]) if traced else 0, round_ms)
     keep(served)
     step_ms = entry.step_ms()[warm_steps:]
     in_window = clock.compiles - compiles0
     degraded = entry.degraded()
     log(f"window {window_s:.3f} s, {rounds} rounds, compiles inside "
         f"{in_window}")
+    log(f"round ms: {json.dumps([round(x, 1) for x in round_ms])}")
+    log(f"step ms: {json.dumps([round(x, 1) for x in step_ms])}")
     mem = [(d.memory_stats() or {}).get("peak_bytes_in_use") for d in cell_devs]
     log(f"peak bytes in use per chip: {mem}")
     entry.close()
@@ -319,7 +351,7 @@ def run(root: pathlib.Path, workload: str, seed: int, seconds: float,
     t = time.perf_counter()
     gap, cg_iters = compare(cell, traffic, kept, cell_devs[0])
     log(f"reference over {sum(len(v) for v in kept.values())} images in "
-        f"{time.perf_counter() - t:.3f} s; fewest CG iterations in a frame "
+        f"{time.perf_counter() - t:.3f} s; least work count of a frame "
         f"{cg_iters}")
     # every frame handed over is served, finite, at the configured
     # accuracy: none shed, rejected or non-finite, and no step down the

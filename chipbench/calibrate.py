@@ -5,10 +5,11 @@
 
 In one process (set-up is paid once): for every seed, the timed path's
 own entry serves rounds 0..check_frames of the cell's traffic at the
-cell's size, and its images are compared with the plain reference,
-exactly as a run compares them (the program's reading).  For every
-control seed, the reference computed in bfloat16 is put in the
-program's place and compared the same way (the control's reading).
+cell's size, and its images are compared with the problem's plain
+reference, exactly as a run compares them (the program's reading).  For
+every control seed, the problem's reference computed one precision step
+down (``lowp=True``; bfloat16 for NLINV) is put in the program's place
+and compared the same way (the control's reading).
 One JSON line per reading; the benchmark's own runs never run this.
 """
 
@@ -37,9 +38,7 @@ def main(argv=None) -> int:
     bench.keep_every_program()
     import jax
 
-    from chipbench import reference
     from chipbench.entries import ENTRIES
-    from chipbench.traffic import make_traffic
     sys.path.insert(0, str(ROOT / "src"))
     from repro.core import Environment
     from repro.core.runtime import use_compile_cache
@@ -54,12 +53,13 @@ def main(argv=None) -> int:
     comm = Environment().subgroup(cell.chips)
     dev0 = list(comm.mesh.devices.flat)[0]
     check = int(cell.mix["check_frames"])
-    cfg = cell.cfg
+    cfg, problem = cell.cfg, cell.problem
     print(json.dumps({"device": devs[0].device_kind, "chips": cell.chips,
                       "cell": cell.name}), flush=True)
     for seed in [int(s) for s in args.seeds.split(",") if s]:
-        traffic = make_traffic(cfg, cell.mix, seed)
-        entry = ENTRIES[cell.mix["entry"]](cfg, cell.mix, traffic, comm)
+        traffic = problem.make_traffic(cfg, cell.mix, seed)
+        entry = ENTRIES[cell.mix["entry"]](cfg, cell.mix, traffic, comm,
+                                           problem)
         kept = collections.defaultdict(dict)
         for f in range(check + 1):
             for s in entry.round(f):
@@ -69,14 +69,11 @@ def main(argv=None) -> int:
         print(json.dumps({"seed": seed, "program": gap, "cg_iters_min": its,
                           "t": time.perf_counter() - T_START}), flush=True)
     for seed in [int(s) for s in args.control_seeds.split(",") if s]:
-        traffic = make_traffic(cfg, cell.mix, seed)
+        traffic = problem.make_traffic(cfg, cell.mix, seed)
         kept = {}
-        for i, mv in enumerate(traffic["movies"]):
-            low, _ = reference.movie(mv["y"], mv["masks"], traffic["fov"],
-                                  newton=int(cfg["newton"]),
-                                  cg_iters=int(cfg["cg_iters"]),
-                                  damping=float(cfg["assumed"]["damping"]),
-                                  frames=check + 1, lowp=True, device=dev0)
+        for i in range(len(traffic["movies"])):
+            low, _ = problem.reference_movie(cfg, traffic, i, check + 1, dev0,
+                                             lowp=True)
             kept[i] = dict(enumerate(low))
         gap, _ = bench.compare(cell, traffic, kept, dev0)
         print(json.dumps({"seed": seed, "control_bf16": gap,

@@ -1,10 +1,12 @@
 """The user entries a window drives, one class per traffic ``entry``.
 
-Each takes the configuration, the mix, the traffic and the
-communicator of the program under test, and serves one frame per scanner
-per ``round``: the closed loop hands a scanner its next frame only once
-the previous image is on the host.  Latency is the benchmark's own clock
-from the hand-over to the image on the host, as a display would have it.
+Each takes the configuration, the mix, the traffic, the communicator of
+the program under test and the cell's problem (``chipbench/problems/``),
+which builds the program and says what one frame is; the entry serves
+one frame per scanner per ``round``: the closed loop hands a scanner its
+next frame only once the previous image is on the host.  Latency is the
+benchmark's own clock from the hand-over to the image on the host, as a
+display would have it.
 """
 
 from __future__ import annotations
@@ -31,37 +33,31 @@ class Served:
 
 
 class ServiceEntry:
-    """Scanners through ``StreamScheduler.open/submit/tick`` over an
-    ``NlinvStreamWorkload``: one batched launch per tick serves one frame
-    of every scanner."""
+    """Scanners through ``StreamScheduler.open/submit/tick`` over the
+    problem's workload: one batched launch per tick serves one frame of
+    every scanner."""
 
-    def __init__(self, cfg, mix, traffic, comm):
-        from repro.nlinv.recon import Reconstructor
-        from repro.serve import (NlinvStreamWorkload, Rejected, ServeConfig,
-                                 StreamScheduler)
+    def __init__(self, cfg, mix, traffic, comm, problem):
+        from repro.serve import Rejected, ServeConfig, StreamScheduler
         self._rejected = Rejected
-        self.traffic = traffic
-        rec = Reconstructor(comm, newton=int(cfg["newton"]),
-                            cg_iters=int(cfg["cg_iters"]),
-                            channel_sum=cfg["channel_sum"])
+        self.frames = int(mix["movie_frames"])
+        workload, opened, self._item = problem.service(cfg, mix, traffic,
+                                                       comm)
         k = int(mix["scanners"])
         self.sched = StreamScheduler(
-            NlinvStreamWorkload(rec, damping=float(cfg["assumed"]["damping"])),
+            workload,
             ServeConfig(max_concurrency=k, buckets=(int(mix["bucket"]),)))
-        self.sessions = [
-            self.sched.open(client=f"scanner{i}", grid=traffic["grid"],
-                            ncoils=traffic["coils"], fov=traffic["fov"])
-            for i in range(k)]
+        self.sessions = [self.sched.open(client=f"scanner{i}", **opened[i])
+                         for i in range(k)]
 
     def round(self, f: int) -> list:
         out, handed = [], []
+        m = f % self.frames
         for i, s in enumerate(self.sessions):
-            mv = self.traffic["movies"][i]
-            m = f % len(mv["y"])
             t0 = time.perf_counter()
             before = len(s.results)
             with _span("submit"):
-                ok = self.sched.submit(s, (mv["y"][m], mv["masks"][m]))
+                ok = self.sched.submit(s, self._item(i, m))
             handed.append((i, s, t0, before))
             if not ok:
                 out.append(Served(i, f, failure="shed"))
@@ -89,49 +85,42 @@ class ServiceEntry:
         return len(self.sched.events)
 
     def close(self):
-        self.sched = self.sessions = None
+        self.sched = self.sessions = self._item = None
 
 
 class StreamEntry:
-    """One scanner through ``FrameStream.run``, one frame per call, the
-    Newton carry handed from call to call with ``carry=``."""
+    """One scanner through the problem's stream call, one frame per
+    call, the carry handed from call to call."""
 
-    def __init__(self, cfg, mix, traffic, comm):
-        from repro.nlinv.recon import Reconstructor
-        from repro.nlinv.stream import FrameStream
+    def __init__(self, cfg, mix, traffic, comm, problem):
         if int(mix["scanners"]) != 1:
             raise ValueError("the stream entry serves one scanner")
-        self.traffic = traffic
-        rec = Reconstructor(comm, newton=int(cfg["newton"]),
-                            cg_iters=int(cfg["cg_iters"]),
-                            channel_sum=cfg["channel_sum"])
-        self.fs = FrameStream(rec, damping=float(cfg["assumed"]["damping"]))
+        self.frames = int(mix["movie_frames"])
+        self._run = problem.stream(cfg, mix, traffic, comm)
         self.carry = None
         self._frame_ms = []
 
     def round(self, f: int) -> list:
-        mv = self.traffic["movies"][0]
-        m = f % len(mv["y"])
+        m = f % self.frames
         t0 = time.perf_counter()
         with _span("step"):
-            imgs, report = self.fs.run(mv["y"][m:m + 1], mv["masks"][m:m + 1],
-                                       self.traffic["fov"], carry=self.carry)
-        self.carry = self.fs.last_carry
+            image, self.carry, frame_ms = self._run(0, m, self.carry)
         with _span("fetch"):
-            img = np.asarray(imgs[0])
-        self._frame_ms.extend(report.frame_ms)
+            img = np.asarray(image)
+        self._frame_ms.extend(frame_ms)
         return [Served(0, f, (time.perf_counter() - t0) * 1e3, img)]
 
     def step_ms(self) -> list:
-        """The program's own timer: ``LatencyReport.frame_ms``."""
+        """The program's own timer, per call (``LatencyReport.frame_ms``
+        for NLINV)."""
         return list(self._frame_ms)
 
     def degraded(self) -> int:
-        """``FrameStream`` has no deadline ladder."""
+        """The stream entry has no deadline ladder."""
         return 0
 
     def close(self):
-        self.fs = self.carry = None
+        self._run = self.carry = None
 
 
 ENTRIES = {"service": ServiceEntry, "stream": StreamEntry}

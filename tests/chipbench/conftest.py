@@ -13,7 +13,7 @@ if str(REPO) not in sys.path:
     sys.path.insert(0, str(REPO))
 
 TINY_CONFIG = {
-    "name": "tiny", "n": 16, "grid": 32, "coils": 4, "newton": 3,
+    "name": "tiny", "problem": "nlinv", "n": 16, "grid": 32, "coils": 4, "newton": 3,
     "cg_iters": 6, "channel_sum": "crop", "chips": 1,
     "precision": "complex64", "assumed": {"spokes": 7, "damping": 0.9},
     "reduced": [],
@@ -35,13 +35,14 @@ def write_root(root: pathlib.Path, cells=(("tiny.service", "tiny-service"),
                chips: int = 1) -> pathlib.Path:
     """A benchmark root holding only files: BENCHMARK.json, a tiny
     configuration, traffic mixes, limits, the repository's metric
-    readers and peak table."""
+    readers, problems and peak table."""
     cb = root / "chipbench"
     for sub in ("configs", "traffic", "limits"):
         (cb / sub).mkdir(parents=True, exist_ok=True)
-    shutil.copytree(REPO / "chipbench" / "metrics", cb / "metrics",
-                    dirs_exist_ok=True,
-                    ignore=shutil.ignore_patterns("__pycache__"))
+    for sub in ("metrics", "problems"):
+        shutil.copytree(REPO / "chipbench" / sub, cb / sub,
+                        dirs_exist_ok=True,
+                        ignore=shutil.ignore_patterns("__pycache__"))
     shutil.copy(REPO / "chipbench" / "peaks.json", cb / "peaks.json")
     cfg = dict(TINY_CONFIG, chips=chips)
     (cb / "configs" / "tiny.json").write_text(json.dumps(cfg))

@@ -79,6 +79,24 @@ def test_serve_window_traces_a_fixed_count_of_rounds():
     assert traced is None and e.rounds == [1]
 
 
+def test_serve_window_times_each_round():
+    class Entry:
+        def round(self, f):
+            time.sleep(0.01 * f)
+            return [f]
+
+    round_ms = []
+    served, window_s, rounds, _ = bench.serve_window(Entry(), 0.0, 1, None,
+                                                     0, round_ms)
+    assert rounds == len(round_ms) == 1 and served == [1]
+    with tempfile.TemporaryDirectory() as d:
+        served, window_s, rounds, _ = bench.serve_window(Entry(), 0.0, 1, d,
+                                                         2, round_ms)
+    assert len(round_ms) == 1 + rounds == 4
+    assert round_ms[2] >= 20 and round_ms[3] >= 30
+    assert sum(round_ms[1:]) <= window_s * 1e3
+
+
 def test_cell_config_mix_and_metric_found_by_new_files(tmp_path):
     root = write_root(tmp_path, cells=(("tiny.new", "tiny-new"),))
     cb = root / "chipbench"
@@ -171,23 +189,32 @@ def test_command_refuses_without_the_program(tmp_path):
     assert r.stdout.strip() == ""
 
 
+def problem_limits(problem: str) -> list:
+    """The ``image_rel_l2`` limits of the cells whose configuration names
+    ``problem``: a control is held to its own problem's limits only."""
+    b = json.loads((REPO / "BENCHMARK.json").read_text())
+    names = {c["name"]: json.loads((REPO / c["file"]).read_text())["problem"]
+             for c in b["configs"]}
+    return [json.loads((REPO / "chipbench" / "limits"
+                        / f"{w['name']}.json").read_text())["image_rel_l2"]
+            for w in b["workloads"] if names[w["config"]] == problem]
+
+
 def test_bf16_control_fails_the_limits():
-    """The control: the reference computed with every array rounded to
-    bfloat16, put in the program's place, reads above every cell's
-    limit (tiny size; the chip readings at the cells' size are in
-    PERF.md)."""
-    from chipbench import traffic
-    cfg = {"n": 32, "coils": 4, "assumed": {"spokes": 11}}
+    """The NLINV control: the problem's reference computed with every
+    array rounded to bfloat16, put in the program's place, reads above
+    the limit of every cell of the NLINV problem (tiny size; the chip
+    readings at the cells' size are in PERF.md)."""
+    problem = bench.load_problem(REPO, "nlinv")
+    cfg = {"n": 32, "coils": 4, "newton": 7, "cg_iters": 20,
+           "assumed": {"spokes": 11, "damping": 0.9}}
     mix = {"scanners": 1, "movie_frames": 3, "noise": 1e-4}
-    limits = [json.loads(p.read_text())["image_rel_l2"]
-              for p in (REPO / "chipbench" / "limits").glob("*.json")]
+    limits = problem_limits("nlinv")
+    assert limits
     for seed in (1, 2**31 + 5, 7):
-        tr = traffic.make_traffic(cfg, mix, seed)
-        mv = tr["movies"][0]
-        kw = dict(newton=7, cg_iters=20, damping=0.9, frames=3)
-        ref, _ = reference.movie(mv["y"], mv["masks"], tr["fov"], **kw)
-        low, _ = reference.movie(mv["y"], mv["masks"], tr["fov"], lowp=True,
-                                 **kw)
+        tr = problem.make_traffic(cfg, mix, seed)
+        ref, _ = problem.reference_movie(cfg, tr, 0, 3, None)
+        low, _ = problem.reference_movie(cfg, tr, 0, 3, None, lowp=True)
         worst = max(reference.rel_l2(a, b) for a, b in zip(low, ref))
         assert worst > max(limits), (seed, worst)
 
