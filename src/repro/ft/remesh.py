@@ -38,18 +38,18 @@ def migrate_carry(rec, u: dict, pad_to: int | None = None) -> dict:
     """Re-place one ``{rho, chat}`` Newton carry onto ``rec``'s group.
 
     ``rho`` is replicated (CLONE) — re-broadcast; ``chat`` is
-    coil-segmented (NATURAL dim 0) — re-scattered, with its coil dim
-    zero-padded to ``pad_to`` (default: the next multiple of the new
-    group size).  Works on live carries and on host trees restored from
+    coil-segmented (NATURAL on ``rec.coil_dim``) — re-scattered, with its
+    coil dim zero-padded to ``pad_to`` (default: the next multiple of the
+    new group size).  Works on live carries and on host trees restored from
     a checkpoint alike (the leaves only need ``np.asarray``).
     """
     rho = np.asarray(u["rho"])
-    chat = np.asarray(u["chat"])
+    chat = np.moveaxis(np.asarray(u["chat"]), rec.coil_dim, 0)
     size = rec.comm.size
     rows = pad_to if pad_to is not None else -(-chat.shape[0] // size) * size
     if rows % size:
         raise ValueError(
             f"carry migration needs the coil dim padded to a multiple of "
             f"the survivor group size {size}; got pad_to={pad_to}")
-    chat = pad_rows(chat, rows)
+    chat = np.moveaxis(pad_rows(chat, rows), 0, rec.coil_dim)
     return {"rho": rec.put_const(rho), "chat": rec.put_frame(chat)}

@@ -11,16 +11,20 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .. import registry as kreg
 from ..registry import KernelSpec, dim_divisible, on_tpu, split
 from .kernel import (coil_adjoint_pallas, coil_forward_pallas,
                      coil_lincomb_pallas, coil_scale_mult_pallas,
-                     plane_mult_pallas)
+                     plane_mult_pallas, sms_adjoint_pallas,
+                     sms_lincomb_pallas)
 from .ref import (coil_adjoint_ref, coil_forward_ref, coil_lincomb_ref,
-                  plane_mult_ref)
+                  plane_mult_ref, sms_adjoint_ref, sms_lincomb_ref)
 
 _LAYOUT = "(J, X, Y) complex stack -> re/im f32, bx-row blocks of X"
+_SMS_LAYOUT = ("(M, J, X, Y) slice x coil stack -> re/im f32, bx-row blocks "
+               "of X, every slice of one channel per step")
 _SPACE = ((8,), (16,), (32,), (64,), (128,))
 
 
@@ -139,6 +143,126 @@ PLANE_MULT = kreg.register(KernelSpec(
     samples=_plane_samples, nsamples=2,
     shape_case=_plane_shape_case,
 ))
+
+
+def _mix(seed, m):
+    """A random (m, m) complex partition-encoding matrix (host constant):
+    the kernels take any mixing, not only the DFT phase cycle."""
+    rng = np.random.default_rng(seed)
+    return (rng.standard_normal((m, m))
+            + 1j * rng.standard_normal((m, m))).astype(np.complex64)
+
+
+def _sms_lincomb_samples(i):
+    m, j, x, y = [(3, 2, 16, 32), (2, 3, 32, 64)][i]
+    ka, kx, kb, ky, ks = jax.random.split(jax.random.PRNGKey(340 + i), 5)
+    a, xs = _cplx(ka, (m, x, y)), _cplx(kx, (m, j, x, y))
+    scale = jax.random.uniform(ks, (x, y), jnp.float32)
+    kw = {"scale": scale, "mix": _mix(340 + i, m)}
+    if i == 0:                            # b=None: G's one-term form
+        return (a, xs), kw, sms_lincomb_ref(a, xs, **kw)
+    b, ys = _cplx(kb, (m, x, y)), _cplx(ky, (m, j, x, y))
+    kw.update(b=b, y=ys)
+    return (a, xs), kw, sms_lincomb_ref(a, xs, **kw)
+
+
+def _sms_adjoint_samples(i):
+    m, j, x, y = [(3, 4, 16, 32), (2, 2, 32, 64)][i]
+    kv, kc, ks, kg = jax.random.split(jax.random.PRNGKey(350 + i), 4)
+    v, c = _cplx(kv, (m, j, x, y)), _cplx(kc, (m, j, x, y))
+    scale = (jax.random.uniform(ks, (x, y)) > 0.3).astype(jnp.float32)
+    g = _cplx(kg, (m, x, y))
+    kw = {"mix": _mix(350 + i, m)}
+    return (v, c, scale, g), kw, sms_adjoint_ref(v, c, scale, g, **kw)
+
+
+def _sms_adjointness(seed=0):
+    """Property: sms_adjoint's unmixing is the adjoint of sms_lincomb's
+    mixing, <lincomb(1, x), v> = <x, adjoint(v)[1]> with unit planes."""
+    m, j, x, y = 3, 2, 16, 32
+    kx, kv = jax.random.split(jax.random.PRNGKey(360 + seed))
+    xs, v = _cplx(kx, (m, j, x, y)), _cplx(kv, (m, j, x, y))
+    ones = jnp.ones((m, x, y), jnp.complex64)
+    mix = _mix(360 + seed, m)
+    fwd = sms_lincomb(ones, xs, mix=mix, impl="pallas")
+    _, back = sms_adjoint(v, jnp.zeros_like(xs), jnp.ones((x, y)), ones,
+                          mix=mix, impl="pallas")
+    lhs, rhs = jnp.vdot(fwd, v), jnp.vdot(xs, back)
+    assert abs(lhs - rhs) <= 1e-4 * abs(lhs), (lhs, rhs)
+
+
+def _sms_supports(block, stack):
+    return stack.ndim == 4 and dim_divisible(stack.shape[2], block[0]) \
+        and stack.shape[1] > 0
+
+
+SMS_LINCOMB = kreg.register(KernelSpec(
+    family="coil_mult", name="sms_lincomb",
+    pallas=sms_lincomb_pallas, ref=sms_lincomb_ref, fallback="jnp",
+    block_args=("bx",), default_block=(32,), block_space=_SPACE,
+    supports=lambda block, a, x, **kw: _sms_supports(block, x),
+    tol=1e-5, layout=_SMS_LAYOUT,
+    samples=_sms_lincomb_samples, nsamples=2,
+))
+
+SMS_ADJOINT = kreg.register(KernelSpec(
+    family="coil_mult", name="sms_adjoint",
+    pallas=sms_adjoint_pallas, ref=sms_adjoint_ref, fallback="jnp",
+    block_args=("bx",), default_block=(32,), block_space=_SPACE,
+    supports=lambda block, v, coils, *a, **kw: _sms_supports(block, v),
+    tol=1e-4, layout=_SMS_LAYOUT,
+    samples=_sms_adjoint_samples, nsamples=2,
+    properties=(_sms_adjointness,),
+))
+
+
+def _static_mix(mix) -> tuple:
+    """The (Q, M) matrix as the kernels' static nested (re, im) tuples."""
+    return tuple(tuple((float(np.real(v)), float(np.imag(v))) for v in row)
+                 for row in np.asarray(mix))
+
+
+def sms_lincomb(a, x, b=None, y=None, scale=None, *, mix, impl="auto",
+                block=None):
+    """out_qj = scale * Sum_m mix[q, m] (a_m x_mj + b_m y_mj) in one fused
+    pass: SMS-NLINV's G/DG pointwise chain with the partition encoding
+    ``mix`` (a host (Q, M) constant) folded in."""
+    impl, block = SMS_LINCOMB.resolve(impl, block, a, x, b=b, y=y,
+                                      scale=scale, mix=mix)
+    if impl != "pallas":
+        return sms_lincomb_ref(a, x, b, y, scale, mix=mix)
+    X, Y = x.shape[-2:]
+    s = jnp.ones((X, Y), jnp.float32) if scale is None \
+        else jnp.asarray(scale, jnp.float32)
+    br = bi = yr = yi = None
+    if b is not None:
+        (br, bi), (yr, yi) = split(b), split(y)
+    zr, zi = sms_lincomb_pallas(*split(a), *split(x), br, bi, yr, yi, s,
+                                mix=_static_mix(mix), bx=block[0],
+                                interpret=not on_tpu())
+    return (zr + 1j * zi).astype(x.dtype)
+
+
+SMS_LINCOMB.dispatch = sms_lincomb
+
+
+def sms_adjoint(v, coils, scale, g, *, mix, impl="auto", block=None):
+    """With z_mj = scale * Sum_q conj(mix[q, m]) v_qj, returns
+    ``(Sum_j conj(coils_mj) z_mj, g_m z_mj)`` in one pass: SMS-NLINV's
+    DG^H unmixing fused into the channel sum and the coil-update
+    product, so z is never written."""
+    impl, block = SMS_ADJOINT.resolve(impl, block, v, coils, scale, g,
+                                      mix=mix)
+    if impl != "pallas":
+        return sms_adjoint_ref(v, coils, scale, g, mix=mix)
+    pr, pi, wr, wi = sms_adjoint_pallas(
+        *split(v), *split(coils), jnp.asarray(scale, jnp.float32), *split(g),
+        mix=_static_mix(mix), bx=block[0], interpret=not on_tpu())
+    return ((pr + 1j * pi).astype(v.dtype),
+            (wr + 1j * wi).astype(v.dtype))
+
+
+SMS_ADJOINT.dispatch = sms_adjoint
 
 
 def coil_forward(coils, x, impl="auto", block=None):

@@ -18,7 +18,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ..kernels.coil_mult import plane_mult
 from .cg import cg, cg_fused
 from .operators import local_reducer, uaxpy, udot, uzeros
 
@@ -76,7 +75,7 @@ def irgnm_fused(ops, y, x0, x_ref=None, *, newton: int = 7,
     # residual mask-supported for arbitrary caller data (a no-op when y
     # is already sampled k-space) — exactness, not an assumption.
     with jax.named_scope("nlinv.mask"):
-        y = plane_mult(y, ops.mask)
+        y = ops.sample(y)
     alpha = jnp.asarray(alpha0, jnp.float32)
     for n in range(newton):
         with jax.named_scope("nlinv.newton"):
@@ -92,7 +91,8 @@ def irgnm_fused(ops, y, x0, x_ref=None, *, newton: int = 7,
 
 
 def postprocess(ops, u):
-    """rho * |c| normalization: the displayed image (RSS-weighted)."""
+    """rho * |c| normalization: the displayed image (RSS-weighted), per
+    slice when the unknowns carry a slice axis."""
     c = ops.coils(u["chat"])
-    rss = jnp.sqrt(jnp.sum(jnp.abs(c) ** 2, axis=0))
+    rss = jnp.sqrt(jnp.sum(jnp.abs(c) ** 2, axis=c.ndim - 3))
     return u["rho"] * rss

@@ -26,7 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..kernels.coil_mult import (coil_adjoint, coil_forward, coil_lincomb,
-                                 plane_mult)
+                                 plane_mult, sms_adjoint, sms_lincomb)
 from ..lib.blas import tree_axpy, tree_vdot
 from ..lib.fft import fft2 as _cfft2
 
@@ -62,6 +62,10 @@ class NlinvOps:
     def coils_adj(self, c):
         """W^H."""
         return fft2c(c) * self.weight
+
+    def sample(self, k):
+        """P_k: the sampling mask applied to a k-space coil stack."""
+        return plane_mult(k, self.mask)
 
     # -- forward model -----------------------------------------------------
     def G(self, u):
@@ -180,23 +184,135 @@ class NlinvOps:
         return ap, pap
 
 
+def sms_phase(slices: int) -> np.ndarray:
+    """Xi_qm = exp(2 pi i q m / M): the RF phase cycle of simultaneous
+    multi-slice excitation, a Fourier encoding along the slice axis
+    (Rosenzweig et al., SMS-NLINV, MRM 2018).  A host constant."""
+    q = np.arange(slices)
+    return np.exp(2j * np.pi * np.outer(q, q) / slices).astype(np.complex64)
+
+
+@dataclasses.dataclass(frozen=True)
+class SmsOps(NlinvOps):
+    """SMS-NLINV: M slices measured together, partition q with its own
+    mask P_q and the slice mixing Xi (``sms_phase``):
+
+        y_qj = P_q F( fov * Sum_m Xi_qm rho_m c_mj )
+
+    Unknowns rho (M, X, Y), chat (M, J, X, Y); masks (Q=M, X, Y).  In
+    DG^H every slice receives every partition's residual,
+    z_mj = Sum_q conj(Xi_qm) fov IFFT(P_q r_qj), so the normal operator
+    couples the slices.  The mixing runs inside the fused coil kernels
+    (``sms_lincomb``, ``sms_adjoint``) under the device scope
+    ``nlinv.sms``; ``precompute`` and ``normal_pap`` are the single-slice
+    ones, which act per leading index."""
+    mix: np.ndarray = None       # (Q, M) complex Xi
+
+    # The 2-D FFTs run one slice at a time (``lax.map``): a slice's (J, X,
+    # Y) transform keeps its intermediates at the single-slice program's
+    # size, which the compiler holds on chip; a (M, J, X, Y) one spills.
+    @staticmethod
+    def _per_slice(f, *xs):
+        return jax.lax.map(lambda a: f(*a), xs)
+
+    def coils(self, chat):
+        return self._per_slice(lambda c: ifft2c(c * self.weight), chat)
+
+    def coils_adj(self, c):
+        return self._per_slice(lambda x: fft2c(x) * self.weight, c)
+
+    def _sampled_fft(self, img):
+        """P_q FFT(img_q), partition by partition."""
+        return self._per_slice(lambda x, m: fft2c(x) * m, img, self.mask)
+
+    def sample(self, k):
+        return k * self.mask[:, None]
+
+    def _mixed(self, a, x, b=None, y=None):
+        with jax.named_scope("nlinv.sms"):
+            return sms_lincomb(a, x, b, y, scale=self.fov, mix=self.mix)
+
+    def _unmixed(self, v, c0, rho0c):
+        with jax.named_scope("nlinv.sms"):
+            return sms_adjoint(v, c0, self.fov, rho0c, mix=self.mix)
+
+    # -- plain operators (the unfused path: no kernels) --------------------
+    def _xi(self, t, adjoint=False):
+        """Sum_m Xi_qm t_m, or with ``adjoint`` Sum_q conj(Xi_qm) t_q."""
+        mix = np.conj(self.mix).T if adjoint else self.mix
+        return jnp.stack([sum(complex(mix[a, b]) * t[b]
+                              for b in range(mix.shape[1]))
+                          for a in range(mix.shape[0])])
+
+    def G(self, u):
+        img = self.fov * self._xi(u["rho"][:, None] * self.coils(u["chat"]))
+        return self.sample(fft2c(img))
+
+    def DG(self, u0, du):
+        img = (du["rho"][:, None] * self.coils(u0["chat"])
+               + u0["rho"][:, None] * self.coils(du["chat"]))
+        return self.sample(fft2c(self.fov * self._xi(img)))
+
+    def DGH(self, u0, r, *, channel_sum=None):
+        z = self.fov * self._xi(ifft2c(self.sample(r)), adjoint=True)
+        prod = jnp.conj(self.coils(u0["chat"])) * z
+        drho = jnp.sum(prod, axis=1) if channel_sum is None \
+            else channel_sum(prod)
+        return {"rho": drho,
+                "chat": self.coils_adj(jnp.conj(u0["rho"])[:, None] * z)}
+
+    # -- fused hot path ----------------------------------------------------
+    def G_fused(self, u, c0=None):
+        c = self.coils(u["chat"]) if c0 is None else c0
+        return self._sampled_fft(self._mixed(u["rho"], c))
+
+    def DG_fused(self, pre, du):
+        return self._sampled_fft(self._mixed(
+            du["rho"], pre["c0"], pre["rho0"], self.coils(du["chat"])))
+
+    def DGH_fused(self, pre, r, *, reducer, extras=(), premasked=True):
+        rin = r if premasked else self.sample(r)
+        # channel sum and conj(rho0) z in one pass; z is never written
+        prod, dz = self._unmixed(self._per_slice(ifft2c, rin), pre["c0"],
+                                 pre["rho0c"])
+
+        def dchat():
+            return self.coils_adj(dz)
+
+        drho, extras_out, dchat_out = reducer(prod, tuple(extras), dchat)
+        return {"rho": drho, "chat": dchat_out}, extras_out
+
+
 def make_ops(mask, fov, weight) -> NlinvOps:
-    return NlinvOps(jnp.asarray(mask, jnp.float32),
-                    jnp.asarray(fov, jnp.float32),
-                    jnp.asarray(weight, jnp.float32))
+    """The operators of one frame's geometry: a (X, Y) mask is the
+    single-slice model, a (M, X, Y) stack of partition masks SMS-NLINV
+    with M slices."""
+    mask = jnp.asarray(mask, jnp.float32)
+    fov = jnp.asarray(fov, jnp.float32)
+    weight = jnp.asarray(weight, jnp.float32)
+    if mask.ndim == 3:
+        return SmsOps(mask, fov, weight, sms_phase(mask.shape[0]))
+    return NlinvOps(mask, fov, weight)
 
 
 # -- pytree algebra for (rho, chat) ----------------------------------------
 
-def uzeros(J, grid, dtype=jnp.complex64):
-    return {"rho": jnp.zeros((grid, grid), dtype),
-            "chat": jnp.zeros((J, grid, grid), dtype)}
+def _lead(slices: int) -> tuple:
+    """The leading slice axis of the unknowns: none for one slice."""
+    return (slices,) if slices > 1 else ()
 
 
-def uinit(J, grid, dtype=jnp.complex64):
-    """Paper/Uecker init: rho = 1, chat = 0."""
-    return {"rho": jnp.ones((grid, grid), dtype),
-            "chat": jnp.zeros((J, grid, grid), dtype)}
+def uzeros(J, grid, dtype=jnp.complex64, slices: int = 1):
+    s = _lead(slices)
+    return {"rho": jnp.zeros(s + (grid, grid), dtype),
+            "chat": jnp.zeros(s + (J, grid, grid), dtype)}
+
+
+def uinit(J, grid, dtype=jnp.complex64, slices: int = 1):
+    """Paper/Uecker init: rho = 1, chat = 0 (per slice)."""
+    s = _lead(slices)
+    return {"rho": jnp.ones(s + (grid, grid), dtype),
+            "chat": jnp.zeros(s + (J, grid, grid), dtype)}
 
 
 def uaxpy(a, x, y):

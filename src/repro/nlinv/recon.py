@@ -40,14 +40,17 @@ _KERNEL_FAMILIES = ("cg_fused", "coil_mult", "masked_allreduce")
 from .irgnm import irgnm, irgnm_fused
 from .operators import make_ops, sobolev_weight, uinit
 
-# Segmentation of the unknown pytree u = {rho, chat} (paper §3.2).
-U_POLICIES = {"rho": Policy.CLONE, "chat": Policy.NATURAL}
 
-# The same decomposition with a leading client-batch dim stacked on: the
-# serving layer solves B independent frames in ONE launch, so the coil
-# split moves to dim 1 of y/chat while rho/mask stay replicated with
-# their batch dim intact.
-U_POLICIES_BATCHED = {"rho": Policy.CLONE, "chat": (Policy.NATURAL, 1)}
+def _coil_policy(dim: int):
+    return Policy.NATURAL if dim == 0 else (Policy.NATURAL, dim)
+
+
+def u_policies(coil_dim: int) -> dict:
+    """Segmentation of the unknown pytree u = {rho, chat} (paper §3.2)
+    with the coil axis at ``coil_dim`` of chat (and of y): 0 for one
+    slice, one further in per leading axis (an SMS slice axis, a
+    client-batch axis)."""
+    return {"rho": Policy.CLONE, "chat": _coil_policy(coil_dim)}
 
 
 def _as_communicator(comm, axis: str) -> Communicator:
@@ -83,12 +86,19 @@ class Reconstructor:
     the fused reduction schedule: ``"psum"`` (one variadic all-reduce)
     or ``"p2p"`` (the chunked ``kern_all_red_p2p_2d`` ppermute ring with
     compute interleaved between transfer rounds).
+
+    ``slices`` > 1 is SMS-NLINV (``operators.SmsOps``): every frame is M
+    slices measured together, ``y`` (M, J, X, Y), ``mask`` (M, X, Y) (one
+    per partition), ``rho`` (M, X, Y), ``chat`` (M, J, X, Y) and the
+    image (M, X, Y); the coils stay split, now on dim 1.  ``slices=1``
+    keeps the single-slice layout and program.
     """
 
     def __init__(self, comm: Communicator | DeviceGroup | None = None,
                  axis: str = "data", *, newton: int = 7, cg_iters: int = 30,
                  channel_sum: str = "crop", hierarchical: bool = False,
-                 fused: bool = True, overlap: str = "psum"):
+                 fused: bool = True, overlap: str = "psum",
+                 slices: int = 1):
         if channel_sum not in ("full", "crop"):
             raise ValueError(f"channel_sum must be full|crop: {channel_sum}")
         if overlap not in ("psum", "p2p"):
@@ -98,6 +108,9 @@ class Reconstructor:
         self.newton, self.cg_iters = newton, cg_iters
         self.channel_sum, self.hierarchical = channel_sum, hierarchical
         self.fused, self.overlap = fused, overlap
+        self.slices = int(slices)
+        # the coil axis of y and chat: behind the slice axis, if any
+        self.coil_dim = 1 if self.slices > 1 else 0
         self.plan_cache = default_cache()
 
     @property
@@ -139,12 +152,12 @@ class Reconstructor:
                 q = g // 4
                 win = ((q, 3 * q), (q, 3 * q)) if crop else None
                 return self.comm.allreduce_window(
-                    prod, win, axis=self.axis, reduce_dim=0,
+                    prod, win, axis=self.axis, reduce_dim=self.coil_dim,
                     hierarchical=self.hierarchical)
 
             def dot(a, b):
                 return self.comm.vdot(a, b, axis=self.axis,
-                                      policies=U_POLICIES)
+                                      policies=u_policies(self.coil_dim))
 
             u = irgnm(ops, y, x0, x_ref, newton=self.newton,
                       cg_iters=self.cg_iters, channel_sum=csum, dot=dot)
@@ -153,24 +166,31 @@ class Reconstructor:
     @jax.named_scope("nlinv.image")
     def _frame_image(self, mask, fov, weight, u):
         """Crop/readout stage: solved ``u`` -> displayed image (the
-        root-sum-of-squares channel combination)."""
+        root-sum-of-squares channel combination, per slice)."""
         ops = make_ops(mask, fov, weight)
         c = ops.coils(u["chat"])
         rss = self.comm.allreduce_window(jnp.abs(c) ** 2, None,
-                                         axis=self.axis, reduce_dim=0)
+                                         axis=self.axis,
+                                         reduce_dim=self.coil_dim)
         return u["rho"] * jnp.sqrt(rss)
 
     def _frame(self, y, mask, fov, weight, x0, x_ref):
         u = self._frame_solve(y, mask, fov, weight, x0, x_ref)
         return u, self._frame_image(mask, fov, weight, u)
 
+    def _policies(self, batched: bool = False):
+        """(y policy, u policies) of the frame program; a client-batch
+        dim moves the coil split one dim further in."""
+        d = self.coil_dim + int(batched)
+        return _coil_policy(d), u_policies(d)
+
     def _build(self, donate: bool):
         clone = Policy.CLONE
-        in_pol = (Policy.NATURAL, clone, clone, clone,
-                  U_POLICIES, U_POLICIES)
+        ypol, upol = self._policies()
+        in_pol = (ypol, clone, clone, clone, upol, upol)
         return self.comm.spmd(self._frame,
                               in_policies=in_pol,
-                              out_policies=(U_POLICIES, clone),
+                              out_policies=(upol, clone),
                               check_vma=False,
                               donate_argnums=(4, 5) if donate else ())
 
@@ -197,11 +217,11 @@ class Reconstructor:
 
     def _build_batched(self, donate: bool):
         clone = Policy.CLONE
-        in_pol = ((Policy.NATURAL, 1), clone, clone, clone,
-                  U_POLICIES_BATCHED, U_POLICIES_BATCHED)
+        ypol, upol = self._policies(batched=True)
+        in_pol = (ypol, clone, clone, clone, upol, upol)
         return self.comm.spmd(self._frame_batched,
                               in_policies=in_pol,
-                              out_policies=(U_POLICIES_BATCHED, clone),
+                              out_policies=(upol, clone),
                               check_vma=False,
                               donate_argnums=(4, 5) if donate else ())
 
@@ -211,8 +231,8 @@ class Reconstructor:
         one visible plan build (never a silent recompile)."""
         key = ("nlinv", "frame_batched", group_token(self.comm), int(width),
                self.newton, self.cg_iters, self.channel_sum,
-               self.hierarchical, self.fused, self.overlap, bool(donate),
-               _kreg.choices_token(_KERNEL_FAMILIES))
+               self.hierarchical, self.fused, self.overlap, self.slices,
+               bool(donate), _kreg.choices_token(_KERNEL_FAMILIES))
         return self.plan_cache.get_or_build(
             key, lambda: Plan(key=key, fn=self._build_batched(donate),
                               lib="nlinv", op="frame_batched"))
@@ -223,7 +243,7 @@ class Reconstructor:
         pure cache hits (and the hit/miss counters prove it)."""
         key = ("nlinv", "frame", group_token(self.comm), self.newton,
                self.cg_iters, self.channel_sum, self.hierarchical,
-               self.fused, self.overlap, bool(donate),
+               self.fused, self.overlap, self.slices, bool(donate),
                _kreg.choices_token(_KERNEL_FAMILIES))
         return self.plan_cache.get_or_build(
             key, lambda: Plan(key=key, fn=self._build(donate),
@@ -232,23 +252,23 @@ class Reconstructor:
     # -- staged plans (the task-graph pipeline's nodes) -------------------
     def _build_solve(self, donate: bool):
         clone = Policy.CLONE
-        in_pol = (Policy.NATURAL, clone, clone, clone,
-                  U_POLICIES, U_POLICIES)
+        ypol, upol = self._policies()
+        in_pol = (ypol, clone, clone, clone, upol, upol)
         return self.comm.spmd(self._frame_solve, in_policies=in_pol,
-                              out_policies=U_POLICIES, check_vma=False,
+                              out_policies=upol, check_vma=False,
                               donate_argnums=(4, 5) if donate else ())
 
     def _build_image(self):
         clone = Policy.CLONE
         return self.comm.spmd(self._frame_image,
                               in_policies=(clone, clone, clone,
-                                           U_POLICIES),
+                                           self._policies()[1]),
                               out_policies=clone, check_vma=False)
 
     def _plan_stage(self, stage: str, builder):
         key = ("nlinv", stage, group_token(self.comm), self.newton,
                self.cg_iters, self.channel_sum, self.hierarchical,
-               self.fused, self.overlap,
+               self.fused, self.overlap, self.slices,
                _kreg.choices_token(_KERNEL_FAMILIES))
         return self.plan_cache.get_or_build(
             key, lambda: Plan(key=key, fn=builder(), lib="nlinv",
@@ -280,7 +300,8 @@ class Reconstructor:
     def fn_batched(self, width: int, *, donate: bool = False):
         """The B-client frame program for batch width ``width``:
         ``(y (B,J,X,Y), mask (B,X,Y), fov, weight, u (B,...), x_ref
-        (B,...)) -> (u, images (B,X,Y))``.  Plan-cached per width."""
+        (B,...)) -> (u, images (B,X,Y))`` (with an SMS slice axis behind
+        B).  Plan-cached per width."""
         return self._plan_batched(width, donate).fn
 
     def __call__(self, y, mask, fov, weight, x0, x_ref):
@@ -288,14 +309,16 @@ class Reconstructor:
 
     # -- carry/constant placement through the verbs -----------------------
     def init_carry(self, ncoils: int, grid: int):
-        """Device-placed Newton carry (rho=1 CLONE, chat=0 NATURAL)."""
-        u = uinit(ncoils, grid)
+        """Device-placed Newton carry (rho=1 CLONE, chat=0 NATURAL), with
+        the reconstructor's ``slices``."""
+        u = uinit(ncoils, grid, slices=self.slices)
         return {"rho": self.comm.bcast(u["rho"]).data,
-                "chat": self.comm.container(u["chat"]).data}
+                "chat": self.put_frame(u["chat"])}
 
     def put_frame(self, y):
-        """Segment one frame of coil data onto the group (coil dim 0)."""
-        return self.comm.container(y).data
+        """Segment one frame of coil data onto the group (on the coil
+        dim, ``coil_dim``)."""
+        return self.comm.container(y, dim=self.coil_dim).data
 
     def put_const(self, x):
         """Replicate a per-frame constant (mask/fov/weight)."""

@@ -117,6 +117,19 @@ class DoubleBuffer:
         return slot
 
 
+def _frames(rec: "Reconstructor", y):
+    """A movie's coil data channel-padded to the group: ``(y, frames,
+    grid, padded coils)``; the coil axis follows the frame axis and the
+    reconstructor's slice axis, if any."""
+    y = np.asarray(y)
+    ax = 1 + rec.coil_dim
+    if y.ndim != 4 + rec.coil_dim:
+        raise ValueError(f"y of shape {y.shape} is not a movie of "
+                         f"{rec.slices}-slice frames")
+    y = pad_channels(y, rec.comm.size, axis=ax)
+    return y, y.shape[0], y.shape[-1], y.shape[ax]
+
+
 @dataclasses.dataclass
 class LatencyReport:
     """Per-frame wall-clock of one streaming run (milliseconds), plus
@@ -182,7 +195,9 @@ class FrameStream:
 
     def run(self, y, masks, fov, *, weight=None, carry=None,
             report_path=None) -> tuple[jax.Array, LatencyReport]:
-        """Reconstruct a movie: y (F, J, X, Y), masks (F, X, Y).
+        """Reconstruct a movie: y (F, J, X, Y), masks (F, X, Y); with the
+        reconstructor's ``slices`` M > 1 (SMS-NLINV) y (F, M, J, X, Y),
+        masks (F, M, X, Y) and images (F, M, X, Y).
 
         Returns (images (F, X, Y), LatencyReport).  Writes the report
         artifact to ``report_path`` when given.  ``carry`` resumes from
@@ -192,11 +207,7 @@ class FrameStream:
         """
         rec = self.recon
         with span("stream.prepare"):
-            y = np.asarray(y)
-            F = y.shape[0]
-            g = y.shape[-1]
-            y = pad_channels(y, rec.comm.size, axis=1)
-            J = y.shape[1]
+            y, F, g, J = _frames(rec, y)
             if weight is None:
                 weight = sobolev_weight(g)
 
@@ -322,11 +333,7 @@ class FramePipeline:
     def run(self, y, masks, fov, *, weight=None, carry=None,
             report_path=None) -> tuple[jax.Array, LatencyReport]:
         rec = self.recon
-        y = np.asarray(y)
-        F = y.shape[0]
-        g = y.shape[-1]
-        y = pad_channels(y, rec.comm.size, axis=1)
-        J = y.shape[1]
+        y, F, g, J = _frames(rec, y)
         if weight is None:
             weight = sobolev_weight(g)
 

@@ -53,13 +53,17 @@ class NlinvStreamWorkload(Workload):
     """B NLINV frame solves per tick, one batched SPMD launch.
 
     Work item (per ``submit``): a ``(y, mask)`` acquisition with ``y``
-    of shape (J, X, Y) (channel-padded here) and ``mask`` (X, Y).
-    Result: the reconstructed (X, Y) image (device array, ready) — or a
+    of shape (J, X, Y) (channel-padded here) and ``mask`` (X, Y); for a
+    reconstructor with ``slices`` M > 1 (SMS-NLINV) ``y`` (M, J, X, Y)
+    and one mask per partition, (M, X, Y).
+    Result: the reconstructed (X, Y) or (M, X, Y) image (device array,
+    ready) — or a
     :class:`~repro.serve.Rejected` status when the health check finds a
     non-finite output (the client is quarantined: its carry row is
     re-initialized in place, every other row is untouched).
-    Geometry (grid, coil count) is fixed per workload — one scanner
-    protocol per scheduler; the first session pins it.
+    Geometry (grid, coil count, ``meta["slices"]``, default 1) is fixed
+    per workload — one scanner protocol per scheduler; the first session
+    pins it, and its slices must be the reconstructor's.
 
     ``retry`` (a ``repro.ft.RestartPolicy``) arms the tick executor's
     transient-task retry; ``operating_points`` is the degradation
@@ -114,7 +118,8 @@ class NlinvStreamWorkload(Workload):
         self.rec.newton, self.rec.cg_iters = self._points[level]
 
     def counters(self) -> dict:
-        return {"retried_tasks": self._exec.retried,
+        return {"slices": self.rec.slices,
+                "retried_tasks": self._exec.retried,
                 "quarantined": self.quarantined,
                 "restacks": self.restacks,
                 "spills": self.spills,
@@ -123,6 +128,12 @@ class NlinvStreamWorkload(Workload):
     # -- session lifecycle ------------------------------------------------
     def open_session(self, session: Session):
         g = int(session.meta["grid"])
+        slices = int(session.meta.get("slices", 1))
+        if slices != self.rec.slices:
+            raise ValueError(
+                f"session slices={slices} do not match the "
+                f"reconstructor's {self.rec.slices}: one protocol per "
+                f"scheduler")
         J = pad_channels(np.zeros((int(session.meta["ncoils"]), 1, 1),
                                   np.complex64),
                          self.rec.comm.size).shape[0]
@@ -146,12 +157,14 @@ class NlinvStreamWorkload(Workload):
         while the current tick's solve is still in flight (the serving
         analogue of FrameStream's double buffer)."""
         y, mask = item
-        y = pad_channels(np.asarray(y), self.rec.comm.size)
-        if self._geom is not None and y.shape[0] < self._geom[0]:
+        ax = self.rec.coil_dim
+        y = pad_channels(np.asarray(y), self.rec.comm.size, axis=ax)
+        if self._geom is not None and y.shape[ax] < self._geom[0]:
             # after an elastic remesh the pinned coil dim can exceed the
             # raw padding (J was padded for the OLD group size); zero
             # channels are exact NLINV no-ops, so top up
-            y = pad_rows(y, self._geom[0])
+            y = np.moveaxis(pad_rows(np.moveaxis(y, ax, 0),
+                                     self._geom[0]), 0, ax)
         return upload_frame(self.rec, y, mask)
 
     def close_session(self, session: Session) -> None:
@@ -289,7 +302,8 @@ class NlinvStreamWorkload(Workload):
                                  cg_iters=old.cg_iters,
                                  channel_sum=old.channel_sum,
                                  hierarchical=old.hierarchical,
-                                 fused=old.fused, overlap=old.overlap)
+                                 fused=old.fused, overlap=old.overlap,
+                                 slices=old.slices)
         self.remeshes += 1
         self._health_jit = None
         if self._geom is None:

@@ -348,6 +348,7 @@ EXPECTED_SPEC_IDS = [
     "cg_fused.cg_update", "cg_fused.xpby_dot",
     "coil_mult.coil_adjoint", "coil_mult.coil_forward",
     "coil_mult.coil_lincomb", "coil_mult.plane_mult",
+    "coil_mult.sms_adjoint", "coil_mult.sms_lincomb",
     "flash_attention.flash_attention",
     "gridding.degrid", "gridding.grid_adjoint",
     "masked_allreduce.masked_sum",

@@ -49,3 +49,61 @@ def test_kernels_implement_dgh_channel_sum():
     got = coil_adjoint(c0, z, mask=None, impl="pallas")
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=1e-4, rtol=1e-4)
+
+
+# -- SMS mixing kernels (interpret mode) against their ref.py twins ---------
+
+_SMS = (3, 4, 32, 32)          # slices M, channels J, grid
+
+
+def _sms_operands(seed):
+    from repro.nlinv.operators import sms_phase
+    m, j, x, y = _SMS
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    planes = [_cplx(k, (m, x, y)) for k in ks[:2]]
+    stacks = [_cplx(k, (m, j, x, y)) for k in ks[2:4]]
+    fov = (jax.random.uniform(ks[4], (x, y)) > 0.4).astype(jnp.float32)
+    return planes, stacks, fov, sms_phase(m)
+
+
+@pytest.mark.parametrize("two_term", [False, True], ids=["G", "DG"])
+def test_sms_lincomb_kernel_matches_ref(two_term):
+    from repro.kernels.coil_mult import sms_lincomb, sms_lincomb_ref
+    (a, b), (x, y), fov, xi = _sms_operands(70 + two_term)
+    kw = dict(b=b, y=y) if two_term else {}
+    got = sms_lincomb(a, x, scale=fov, mix=xi, impl="pallas", **kw)
+    want = sms_lincomb_ref(a, x, scale=fov, mix=xi, **kw)
+    assert got.shape == (_SMS[0],) + x.shape[1:]
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+
+
+def test_sms_adjoint_kernel_matches_ref():
+    from repro.kernels.coil_mult import sms_adjoint, sms_adjoint_ref
+    (g, _), (v, c), fov, xi = _sms_operands(72)
+    got = sms_adjoint(v, c, fov, g, mix=xi, impl="pallas")
+    want = sms_adjoint_ref(v, c, fov, g, mix=xi)
+    for a, b in zip(got, want, strict=True):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-4)
+
+
+def test_sms_kernels_implement_the_sms_operator():
+    """The fused SMS chains equal the plain SmsOps algebra: the forward
+    mixing Sum_m Xi_qm fov rho_m c_mj, and the unmixed channel sum."""
+    from repro.kernels.coil_mult import sms_adjoint, sms_lincomb
+    from repro.nlinv.operators import make_ops, sobolev_weight
+    (rho, _), (c, v), fov, xi = _sms_operands(73)
+    mask = np.ones((_SMS[0],) + _SMS[2:], np.float32)
+    ops = make_ops(mask, fov, sobolev_weight(_SMS[2]))
+    want = fov * ops._xi(rho[:, None] * c)
+    got = sms_lincomb(rho, c, scale=fov, mix=xi, impl="pallas")
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+    z = fov * ops._xi(v, adjoint=True)
+    prod, _ = sms_adjoint(v, c, fov, jnp.ones_like(rho), mix=xi,
+                          impl="pallas")
+    np.testing.assert_allclose(np.asarray(prod),
+                               np.asarray(jnp.sum(jnp.conj(c) * z, axis=1)),
+                               rtol=1e-4, atol=1e-4)

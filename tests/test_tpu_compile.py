@@ -15,6 +15,7 @@ the TPU library.
 """
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -27,16 +28,25 @@ from repro.kernels.coil_mult.kernel import (coil_adjoint_pallas,
                                             coil_forward_pallas,
                                             coil_lincomb_pallas,
                                             coil_scale_mult_pallas,
-                                            plane_mult_pallas)
+                                            plane_mult_pallas,
+                                            sms_adjoint_pallas,
+                                            sms_lincomb_pallas)
 from repro.kernels.masked_allreduce.kernel import masked_sum_pallas
 
 J, G = 8, 768           # coil channels, doubled grid (bench fig6 paper size)
+M = 3                   # SMS slices (the sms3 benchmark configuration)
 BATCH = 4               # clients per batched launch
 
 _STACK = (J, G, G)      # (J, X, Y) coil stack plane
 _PLANE = (G, G)         # (X, Y) image plane
 _ROWS = (J * G, G)      # chat leaf flattened to (M, Y) row planes
 _SCALAR = (1, 1)        # SMEM scalar operand
+_SMS_STACK = (M, J, G, G)   # (M, J, X, Y) slice x coil stack
+_SMS_PLANE = (M, G, G)      # (M, X, Y) slice planes
+# the DFT phase cycle exp(2 pi i q m / M) as the kernels' static tuples
+_XI = tuple(tuple((math.cos(2 * math.pi * q * m / M),
+                   math.sin(2 * math.pi * q * m / M)) for m in range(M))
+            for q in range(M))
 
 # kernel name -> (Pallas entry, block kwarg, operand shapes, all f32)
 KERNELS = {
@@ -54,6 +64,16 @@ KERNELS = {
     "coil_adjoint": (coil_adjoint_pallas, "bx",
                      [_STACK, _STACK, _STACK, _STACK, _PLANE]),
     "masked_sum": (masked_sum_pallas, "bx", [(4, G, G)] * 2 + [_PLANE]),
+    "sms_lincomb": (functools.partial(sms_lincomb_pallas, mix=_XI), "bx",
+                    [_SMS_PLANE, _SMS_PLANE, _SMS_STACK, _SMS_STACK,
+                     _SMS_PLANE, _SMS_PLANE, _SMS_STACK, _SMS_STACK,
+                     _PLANE]),
+    "sms_lincomb_one": (
+        lambda ar, ai, xr, xi, s, **kw: sms_lincomb_pallas(
+            ar, ai, xr, xi, None, None, None, None, s, mix=_XI, **kw),
+        "bx", [_SMS_PLANE, _SMS_PLANE, _SMS_STACK, _SMS_STACK, _PLANE]),
+    "sms_adjoint": (functools.partial(sms_adjoint_pallas, mix=_XI), "bx",
+                    [_SMS_STACK] * 4 + [_PLANE, _SMS_PLANE, _SMS_PLANE]),
 }
 
 
