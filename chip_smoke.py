@@ -76,7 +76,8 @@ RTOL = 1e-2
 # the kernel specs the frame program traces through
 MAIN_PATH_SPECS = ("cg_fused.cg_update", "cg_fused.xpby_dot",
                    "coil_mult.coil_lincomb", "coil_mult.plane_mult",
-                   "coil_mult.coil_adjoint", "coil_mult.coil_forward")
+                   "coil_mult.coil_adjoint", "coil_mult.coil_forward",
+                   "dft.dft2c")
 
 _BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 _CACHE_HIT = "/jax/compilation_cache/cache_hits"

@@ -14,6 +14,7 @@ parity/property harness in ``tests/test_kernel_registry.py`` runs on.
   coil_mult         NLINV coil pointwise C / fused channel-summed C^H
   gridding          separable-matrix (de)gridding as MXU matmuls
   cg_fused          single-pass CG updates with dot epilogues
+  dft               centred 2-D DFT as MXU matmuls (r x 128 factorisation)
   masked_allreduce  fused masked partial-image sum (kern_all_red_p2p_2d)
 """
 

@@ -24,6 +24,7 @@ Distribution contract (paper §2.4):
 
 from __future__ import annotations
 
+import collections
 import functools
 
 import jax
@@ -32,6 +33,8 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from ..core.segmented import Policy, SegmentedArray
+from ..kernels.dft import dft2c, dft_engages
+from ..kernels.registry import choices_token
 from .plan import Plan, PlanCache, default_cache, seg_token
 
 
@@ -46,15 +49,14 @@ def _fft1_local(x: jax.Array, axis: int, inverse: bool,
     return x
 
 
-def _fft2_local(x: jax.Array, inverse: bool, centered: bool) -> jax.Array:
-    axes = (-2, -1)
-    if centered:
-        x = jnp.fft.ifftshift(x, axes=axes)
-    x = (jnp.fft.ifft2(x, axes=axes, norm="ortho") if inverse
-         else jnp.fft.fft2(x, axes=axes, norm="ortho"))
-    if centered:
-        x = jnp.fft.fftshift(x, axes=axes)
-    return x
+_IMPL_BUILDS: collections.Counter = collections.Counter()
+
+
+def impl_builds() -> dict:
+    """Plain-array FFT plans built so far, per ``meta["impl"]``
+    (``dft_pallas`` or ``xla``): the kernel's engagement counter, beside
+    ``PlanCache.builds``."""
+    return dict(_IMPL_BUILDS)
 
 
 # ---------------------------------------------------------------------------
@@ -68,14 +70,19 @@ def plan_fft2(shape, dtype, *, inverse: bool = False, centered: bool = False,
     jit/shard_map traces (it is itself a jitted program)."""
     cache = default_cache() if cache is None else cache
     key = ("fft", "fft2", tuple(shape), str(jnp.dtype(dtype)),
-           bool(inverse), bool(centered))
+           bool(inverse), bool(centered), choices_token(("dft",)))
 
     def build():
-        fn = jax.jit(functools.partial(_fft2_local, inverse=inverse,
+        # the Pallas DFT kernel where it takes the stack on a TPU (complex64
+        # square planes of side r * 128), XLA's FFT everywhere else
+        fn = jax.jit(functools.partial(dft2c, inverse=inverse,
                                        centered=centered))
+        engages = dft_engages(jax.ShapeDtypeStruct(tuple(shape), dtype))
+        impl = "dft_pallas" if engages else "xla"
+        _IMPL_BUILDS[impl] += 1
         return Plan(key=key, fn=fn, lib="fft", op="fft2",
                     meta={"shape": tuple(shape), "inverse": inverse,
-                          "centered": centered})
+                          "centered": centered, "impl": impl})
 
     return cache.get_or_build(key, build)
 
@@ -173,7 +180,7 @@ def _build_fft2_fused(seg: SegmentedArray, inverse: bool, centered: bool,
 def _build_fft2_batched(seg: SegmentedArray, inverse: bool, centered: bool):
     """Build the executor for one container geometry.  Returns
     ``(fn, meta)`` where meta records the schedule picked."""
-    local = functools.partial(_fft2_local, inverse=inverse, centered=centered)
+    local = functools.partial(dft2c, inverse=inverse, centered=centered)
     if not _dim_in_plane(seg):
         # batch segmented (or CLONE): shard-local batched FFT, no comm.
         if seg.policy is Policy.CLONE:

@@ -36,7 +36,7 @@ from ..lib.plan import Plan, default_cache, group_token
 
 # the kernel families the frame program traces through; their current
 # block choices are part of the frame-plan identity
-_KERNEL_FAMILIES = ("cg_fused", "coil_mult", "masked_allreduce")
+_KERNEL_FAMILIES = ("cg_fused", "coil_mult", "dft", "masked_allreduce")
 from .irgnm import irgnm, irgnm_fused
 from .operators import make_ops, sobolev_weight, uinit
 

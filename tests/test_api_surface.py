@@ -349,6 +349,7 @@ EXPECTED_SPEC_IDS = [
     "coil_mult.coil_adjoint", "coil_mult.coil_forward",
     "coil_mult.coil_lincomb", "coil_mult.plane_mult",
     "coil_mult.sms_adjoint", "coil_mult.sms_lincomb",
+    "dft.dft2c",
     "flash_attention.flash_attention",
     "gridding.degrid", "gridding.grid_adjoint",
     "masked_allreduce.masked_sum",

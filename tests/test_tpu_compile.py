@@ -112,3 +112,87 @@ def test_kernel_compiles_for_v5e(one_chip, name, batched):
             for s in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# -- the 2-D DFT kernel (repro.kernels.dft) at the cells' grid -----------
+
+def _dft_args(one_chip, lead):
+    from repro.kernels.dft.kernel import LANES
+    plane = jax.ShapeDtypeStruct(lead + (G, G), jnp.float32,
+                                 sharding=one_chip)
+    g = jax.ShapeDtypeStruct((G // LANES, 2 * LANES, 2 * LANES),
+                             jnp.float32, sharding=one_chip)
+    return plane, plane, g
+
+
+def _dft_alone(xr, xi, g):
+    from repro.kernels.dft.kernel import dft2c_pallas
+    return dft2c_pallas(xr, xi, g, interpret=False)
+
+
+def _dft_slices(xr, xi, g):
+    """SMS-NLINV's form: the slices one after another under ``lax.map``."""
+    return jax.lax.map(lambda p: _dft_alone(*p, g), (xr, xi))
+
+
+def _dft_in_loop(xr, xi, g):
+    """The CG loop's form: the transform inside ``fori_loop``."""
+    return jax.lax.fori_loop(0, 2, lambda i, p: _dft_alone(*p, g), (xr, xi))
+
+
+@pytest.mark.parametrize("form,lead", [
+    (_dft_alone, (J,)), (_dft_alone, (2,)),
+    (_dft_slices, (M, J)), (_dft_in_loop, (J,))],
+    ids=["alone-8", "alone-2", "lax.map-3x8", "fori_loop-8"])
+def test_dft_compiles_for_v5e(one_chip, form, lead):
+    compiled = jax.jit(form).lower(*_dft_args(one_chip, lead)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_frame_program_at_grid_768_runs_no_xla_fft(topo, monkeypatch):
+    """The single-slice frame program at the cells' size, lowered for a
+    described chip as a TPU would build it, holds the DFT kernel and no
+    XLA FFT: the kernel engages by shape."""
+    import importlib
+    import pkgutil
+
+    import repro.kernels as kernels
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.core.env import Communicator
+    from repro.core.runtime import DeviceGroup
+    from repro.kernels import registry
+    from repro.lib import fft as lfft
+    from repro.lib.plan import PlanCache, default_cache
+    from repro.nlinv.recon import Reconstructor
+
+    registry.specs()
+    for m in [registry] + [
+            importlib.import_module(f"repro.kernels.{p.name}.ops")
+            for p in pkgutil.iter_modules(kernels.__path__) if p.ispkg]:
+        if hasattr(m, "on_tpu"):
+            monkeypatch.setattr(m, "on_tpu", lambda: True)
+    mesh = Mesh(topo.devices[:1], ("data",))
+    rec = Reconstructor(Communicator(DeviceGroup.from_mesh(mesh), "data"),
+                        newton=7, cg_iters=20)
+    rec.plan_cache = PlanCache()
+    rep = NamedSharding(mesh, P())
+    coil = NamedSharding(mesh, P(None, "data"))
+    S = jax.ShapeDtypeStruct
+    u = {"rho": S((2, G, G), jnp.complex64, sharding=rep),
+         "chat": S((2, J, G, G), jnp.complex64, sharding=coil)}
+    args = (S((2, J, G, G), jnp.complex64, sharding=coil),
+            S((2, G, G), jnp.float32, sharding=rep),
+            S((G, G), jnp.float32, sharding=rep),
+            S((G, G), jnp.float32, sharding=rep), u, u)
+    default_cache().clear()
+    try:
+        before = lfft.impl_builds()
+        text = rec.fn_batched(2).lower(*args).as_text()
+        after = lfft.impl_builds()
+    finally:
+        default_cache().clear()
+    assert "stablehlo.fft" not in text
+    assert "tpu_custom_call" in text and "dft2c_pallas" in text
+    assert after.get("dft_pallas", 0) - before.get("dft_pallas", 0) == 2
+    assert after.get("xla", 0) == before.get("xla", 0)
